@@ -1,10 +1,10 @@
-"""smollm-360m [dense] — llama-arch small. [hf:HuggingFaceTB/SmolLM-135M]"""
+"""smollm-360m [dense] — llama-arch small. [hf:HuggingFaceTB/SmolLM-360M]"""
 from repro.configs.base import ModelConfig
 
 CONFIG = ModelConfig(
     name="smollm-360m",
     arch_type="dense",
-    source="hf:HuggingFaceTB/SmolLM-135M",
+    source="hf:HuggingFaceTB/SmolLM-360M",
     n_layers=32,
     d_model=960,
     n_heads=15,

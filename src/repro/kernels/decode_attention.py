@@ -43,6 +43,15 @@ from jax.experimental.pallas import tpu as pltpu
 NEG_INF = -1e30
 
 
+def _block_mask(valid, bs: int):
+    """(B, S) validity -> (B, S/bs, 1, bs) int32, so each program's mask
+    block is (1, bs): the full extent of the array's last two dims, which
+    Mosaic accepts for any ``bs`` (a (1, bs) block of a 2-D (B, S) array
+    is not a multiple of the (8, 128) tile unless ``bs`` is)."""
+    b, s = valid.shape
+    return valid.astype(jnp.int32).reshape(b, s // bs, 1, bs)
+
+
 def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_s, l_s, acc_s, *,
             bs: int, n_s: int, g: int, scale: float):
     si = pl.program_id(2)
@@ -57,7 +66,7 @@ def _kernel(q_ref, k_ref, v_ref, valid_ref, o_ref, m_s, l_s, acc_s, *,
     k = k_ref[0, 0, :, :]                             # (bs, d)
     v = v_ref[0, 0, :, :]
     s = jnp.dot(q, k.T, preferred_element_type=jnp.float32)   # (G, bs)
-    ok = valid_ref[0, :][None, :]                     # (1, bs)
+    ok = valid_ref[0, 0, :, :] > 0                    # (1, bs)
     s = jnp.where(ok, s, NEG_INF)
     m_prev = m_s[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -92,7 +101,7 @@ def flash_decode(q, k, v, valid, *, bs: int = 512, interpret: bool = True):
             pl.BlockSpec((1, 1, g, d), lambda b_, kv, si: (b_, kv, 0, 0)),
             pl.BlockSpec((1, 1, bs, d), lambda b_, kv, si: (b_, kv, si, 0)),
             pl.BlockSpec((1, 1, bs, d), lambda b_, kv, si: (b_, kv, si, 0)),
-            pl.BlockSpec((1, bs), lambda b_, kv, si: (b_, si)),
+            pl.BlockSpec((1, 1, 1, bs), lambda b_, kv, si: (b_, si, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda b_, kv, si: (b_, kv, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((b, n_kv, g, d), q.dtype),
@@ -102,7 +111,7 @@ def flash_decode(q, k, v, valid, *, bs: int = 512, interpret: bool = True):
             pltpu.VMEM((g, d), jnp.float32),
         ],
         interpret=interpret,
-    )(qg, k, v, valid)
+    )(qg, k, v, _block_mask(valid, bs))
     return out.reshape(b, h, d)
 
 
@@ -142,7 +151,7 @@ def _paged_kernel(tables_ref, q_ref, k_ref, v_ref, valid_ref, *rest,
         k = k.astype(jnp.float32) * ks_ref[0, 0, :, :]
         v = v.astype(jnp.float32) * vs_ref[0, 0, :, :]
     s = jnp.dot(q, k.astype(q.dtype).T, preferred_element_type=jnp.float32)
-    ok = valid_ref[0, :][None, :]                     # (1, bs)
+    ok = valid_ref[0, 0, :, :] > 0                    # (1, bs)
     s = jnp.where(ok, s, NEG_INF)
     m_prev = m_s[...]
     m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
@@ -184,9 +193,9 @@ def _paged_attend(qg, k_pages, v_pages, block_tables, valid,
         pl.BlockSpec((1, 1, r, d), lambda b_, kv, bi, tbl: (b_, kv, 0, 0)),
         page_spec,
         page_spec,
-        pl.BlockSpec((1, bs), lambda b_, kv, bi, tbl: (b_, bi)),
+        pl.BlockSpec((1, 1, 1, bs), lambda b_, kv, bi, tbl: (b_, bi, 0, 0)),
     ]
-    operands = [qg, k_pages, v_pages, valid]
+    operands = [qg, k_pages, v_pages, _block_mask(valid, bs)]
     if quantized:
         scale_spec = pl.BlockSpec(
             (1, 1, bs, 1), lambda b_, kv, bi, tbl: (tbl[b_, bi], kv, 0, 0))

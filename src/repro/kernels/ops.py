@@ -1,15 +1,18 @@
 """jit'd public wrappers for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernel bodies execute in interpret mode, which is how correctness is
-validated here) and to False on TPU, where the Mosaic-compiled kernels are
-the production hot path.  ``REPRO_PALLAS_INTERPRET=1|0`` overrides the
-autodetection — CI's kernel-parity job forces ``1`` so the fused serving
-step is exercised through the Pallas machinery on every PR.
+``interpret`` defaults to True off-TPU (the kernel bodies execute in
+interpret mode, which is how correctness is validated on CPU) and to False
+on TPU, where the Mosaic-compiled kernels are the production hot path.
+``REPRO_PALLAS_INTERPRET=1|0`` overrides the autodetection off-TPU — CI's
+kernel-parity job forces ``1`` so the fused serving step is exercised
+through the Pallas machinery on every PR.  On a TPU backend interpret mode
+is refused outright (``resolve_interpret`` raises): a run on the chip that
+silently interpreted its kernels would measure the interpreter.
 """
 from __future__ import annotations
 
 import os
+from typing import Optional
 
 import jax
 
@@ -35,9 +38,22 @@ def default_interpret() -> bool:
     return not on_tpu()
 
 
+def resolve_interpret(interpret: Optional[bool] = None) -> bool:
+    """The ``interpret`` flag a kernel call site uses: the explicit value,
+    else ``default_interpret()``.  Raises on a TPU backend if that comes out
+    True — the chip always runs the Mosaic-compiled kernels."""
+    interp = default_interpret() if interpret is None else bool(interpret)
+    if interp and on_tpu():
+        raise RuntimeError(
+            "Pallas interpret mode requested on a TPU backend (explicit "
+            "interpret=True or REPRO_PALLAS_INTERPRET); unset it — the chip "
+            "runs the compiled kernels")
+    return interp
+
+
 __all__ = ["ProbeStepOut", "SpecProbeOut", "ttt_probe_scan",
            "ttt_probe_batched", "make_unroll_kernel", "serving_probe_step",
            "serving_probe_spec_step", "flash_attention",
            "flash_decode", "paged_flash_decode", "paged_flash_packed_chunk",
            "paged_flash_prefill_chunk", "wkv_scan", "on_tpu",
-           "default_interpret"]
+           "default_interpret", "resolve_interpret"]

@@ -250,9 +250,9 @@ def _spec_kernel(zq_ref, zk_ref, bnd_ref, acc_ref, w_ref, b_ref, ring_ref,
 
     def body(t, carry):
         w, b, ring, n0, stopped_f, step0 = carry
-        zq = pl.load(zq_ref, (pl.dslice(t, 1), slice(None), slice(None)))[0]
-        zk = pl.load(zk_ref, (pl.dslice(t, 1), slice(None), slice(None)))[0]
-        bnd_in = pl.load(bnd_ref, (pl.dslice(t, 1), slice(None)))[0]
+        zq = zq_ref[pl.ds(t, 1), :, :][0]
+        zk = zk_ref[pl.ds(t, 1), :, :][0]
+        bnd_in = bnd_ref[pl.ds(t, 1), :][0]
         stopped = stopped_f > 0.5
         # the accepted-length mask composes with the frozen-stop mask: a
         # rejected draft position or a slot stopped earlier IN THIS CHAIN
@@ -274,9 +274,9 @@ def _spec_kernel(zq_ref, zk_ref, bnd_ref, acc_ref, w_ref, b_ref, ring_ref,
         stop_now = bnd_b & (smoothed >= lam) & (n > burn_in)
         stopped_new = stopped | stop_now
         step_new = jnp.where(stop_now & (step0 < 0), n, step0)
-        pl.store(s_out, (pl.dslice(t, 1), slice(None)), s[None])
-        pl.store(sm_seq_out, (pl.dslice(t, 1), slice(None)), smoothed[None])
-        pl.store(n_seq_out, (pl.dslice(t, 1), slice(None)), n[None])
+        s_out[pl.ds(t, 1), :] = s[None]
+        sm_seq_out[pl.ds(t, 1), :] = smoothed[None]
+        n_seq_out[pl.ds(t, 1), :] = n[None]
         return (jnp.where(stop_now[:, None], w, w_upd),
                 jnp.where(stop_now, b, b_upd),
                 ring_new, n, stopped_new.astype(jnp.float32), step_new)

@@ -31,7 +31,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import ALIASES, INPUT_SHAPES, InputShape, get_config
 from repro.core.probe import ProbeConfig, init_outer
 from repro.launch import shardings as SH
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.models import build
 from repro.optim import Adam
 from repro.parallel import use_parallel
@@ -226,7 +226,8 @@ def lower_pair(arch: str, shape_name: str, *, multi_pod: bool,
         if hlo_out:
             with open(hlo_out, "w") as f:
                 f.write(hlo)
-        report = build_report(cfg, shape, mesh_name, chips, hlo, cost=cost,
+        report = build_report(cfg, shape, mesh_name, chips, hlo,
+                              PRODUCTION_DEVICE_KIND, cost=cost,
                               memory_stats=memory_stats)
         result["roofline"] = json.loads(report.to_json())
         result["options"] = {"microbatches": microbatches,
@@ -259,4 +260,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     sys.exit(main())

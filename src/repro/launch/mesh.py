@@ -14,6 +14,11 @@ from typing import Tuple
 import numpy as np
 
 import jax
+from jax.sharding import AxisType
+
+# the chip the production meshes are laid out for (its roofline peaks are
+# repro.roofline.constants.PEAKS[PRODUCTION_DEVICE_KIND])
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -26,7 +31,14 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)} — "
             "set XLA_FLAGS=--xla_force_host_platform_device_count=512 BEFORE "
             "importing jax (launch/dryrun.py does this)")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])
+
+
+def _auto_mesh(shape, axes, devices):
+    # model code annotates activations with ``with_sharding_constraint``,
+    # which only accepts Auto axes (make_mesh defaults to Explicit)
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
@@ -36,4 +48,4 @@ def make_test_mesh(shape: Tuple[int, ...] = (2, 2),
     devices = jax.devices()
     if len(devices) < n:
         raise RuntimeError(f"need {n} devices, have {len(devices)}")
-    return jax.make_mesh(shape, axes, devices=devices[:n])
+    return _auto_mesh(shape, axes, devices[:n])

@@ -62,7 +62,7 @@ def trajectories_from_model(model, params, n: int, prompt_len: int,
                          dist=TrajectoryDistribution("model"))
 
 
-def main(argv=None) -> int:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True)
     ap.add_argument("--reduced", action="store_true")
@@ -149,13 +149,15 @@ def main(argv=None) -> int:
                     help="fleet placement policy (--hosts > 1): 'pressure' "
                          "= least-loaded with prefix affinity, "
                          "'roundrobin' = locality-blind rotation")
-    args = ap.parse_args(argv)
+    return ap
 
-    cfg = get_config(args.arch)
-    if args.reduced:
-        cfg = cfg.reduced()
-    model = build(cfg)
-    params = model.init(jax.random.PRNGKey(args.seed))
+
+def calibrate(args, model, params):
+    """Meta-train the TTT probe on trajectories harvested from ``model``
+    and LTT-calibrate it at ``args.delta``.  Returns (calibrator, lambda*,
+    consensus calibrator or None, whether LTT selected a threshold — False
+    means the demo fallback lambda is in use)."""
+    cfg = model.cfg
     print(f"[serve] {cfg.name}: harvesting {args.train_trajectories} "
           "calibration trajectories from the model")
     ts = trajectories_from_model(model, params, args.train_trajectories,
@@ -168,9 +170,15 @@ def main(argv=None) -> int:
     calib = orca.fit(train, mode="consistent", method="ttt",
                      pc=ProbeConfig(d_phi=cfg.d_model, smooth_window=4),
                      epochs=args.epochs, epoch_select=False, seed=args.seed)
-    # demo fallback keeps eviction observable on tiny random-weight models
-    lam = orca.calibrated_lambda(calib, cal, args.delta, fallback=0.99)
-    print(f"[serve] LTT-calibrated lambda* = {lam:.3f}")
+    lam = orca.calibrated_lambda(calib, cal, args.delta)
+    calibrated = bool(np.isfinite(lam))
+    if calibrated:
+        print(f"[serve] LTT-calibrated lambda* = {lam:.3f}")
+    else:
+        # demo fallback keeps eviction observable on random-weight models
+        lam = 0.99
+        print(f"[serve] LTT selected no threshold at delta={args.delta}; "
+              f"demo fallback lambda = {lam:.3f}")
 
     # group consensus: LTT-calibrate the agreement threshold over groups
     # formed from the calibration split (group-level exchangeability),
@@ -191,7 +199,14 @@ def main(argv=None) -> int:
         consensus = g_cal
         print(f"[serve] consensus threshold g* = {g_cal.lam:.3f} "
               f"(delta={c_delta}, {len(traces)} calibration groups)")
+    return calib, lam, consensus, calibrated
 
+
+def serve(args, model, params, calib, lam: float, consensus=None):
+    """Serve ``args.requests`` synthetic prompts through ``repro.api`` with
+    the flags in ``args`` and print the per-request lifecycle and fleet
+    metrics.  Returns (scheduler or router, finished requests, metrics)."""
+    cfg = model.cfg
     # the ~20 CLI flags become ONE ServeConfig: from_args maps the flag
     # names (slots -> n_slots, no_pack -> pack_chunks, 0 -> None for the
     # optional ints); runtime-computed values ride in as overrides
@@ -204,6 +219,9 @@ def main(argv=None) -> int:
         sched = orca.fleet(model, params, calib, config=serve_cfg)
         print(f"[serve] fleet: {args.hosts} hosts x {args.slots} slots, "
               f"placement={args.placement}")
+        for i, h in enumerate(sched.hosts):
+            print(f"[serve]   host {i}: device {h.device.id} "
+                  f"({h.device.platform}, {h.device.device_kind})")
     else:
         sched = orca.engine(model, params, calib, config=serve_cfg)
     batch = model_inputs(cfg, jax.random.PRNGKey(args.seed + 1),
@@ -279,13 +297,26 @@ def main(argv=None) -> int:
              if args.chunk_tokens else " (admission-time prefill)"))
     for key in sorted(fleet.per_class):
         print(f"[serve]   {key}: {fleet.per_class[key]:.1f}")
+    return sched, done, fleet
 
+
+def main(argv=None) -> int:
+    args = build_parser().parse_args(argv)
+    cfg = get_config(args.arch)
+    if args.reduced:
+        cfg = cfg.reduced()
+    model = build(cfg)
+    params = model.init(jax.random.PRNGKey(args.seed))
+    calib, lam, consensus, _ = calibrate(args, model, params)
+    serve(args, model, params, calib, lam, consensus)
     if args.static_baseline:
         pc, theta = calib.serving_params()
         scfg = ServeConfig(tokens_per_step=args.tokens_per_step,
                            max_new_tokens=args.max_new_tokens,
                            lam=float(lam), burn_in=args.burn_in)
         eng = ServingEngine(model, params, pc, theta, scfg)
+        batch = model_inputs(cfg, jax.random.PRNGKey(args.seed + 1),
+                             args.requests, args.prompt_len)
         base = serve_queue_static(eng, batch, args.prompt_len, args.slots)
         print(f"[serve] static-batch baseline: {base.engine_steps} engine "
               f"steps ({base.wall_time_s:.2f}s) — "
@@ -294,4 +325,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro.launch.compile_cache import use_compile_cache
+    use_compile_cache()
     raise SystemExit(main())

@@ -381,10 +381,10 @@ def attn_prefill_chunk(q, k_new, v_new, cache_l: Dict[str, jnp.ndarray],
     vb = v_new.transpose(0, 2, 1, 3).astype(jnp.float32)
     causal = jnp.arange(c)[:, None] >= jnp.arange(c)[None, :]
     paged = block_tables is not None
-    impl = impl or (default_paged_impl() if paged else "jnp")
+    impl = resolve_paged_impl(impl) if paged else "jnp"
     if paged and impl == "pallas":
         from repro.kernels import ops as K           # deferred: no cycle
-        interp = K.default_interpret() if interpret is None else interpret
+        interp = K.resolve_interpret(interpret)
         o, l, m = K.paged_flash_prefill_chunk(
             q.astype(jnp.float32), cache_l["k"], cache_l["v"], block_tables,
             valid, cache_l.get("k_scale"), cache_l.get("v_scale"),
@@ -509,10 +509,10 @@ def attn_prefill_packed(q, k_new, v_new, cache_l: Dict[str, jnp.ndarray],
     seg = jnp.asarray(seg, jnp.int32)
     seg_starts = jnp.asarray(seg_starts, jnp.int32)
     paged = seg_tables is not None
-    impl = impl or (default_paged_impl() if paged else "jnp")
+    impl = resolve_paged_impl(impl) if paged else "jnp"
     if paged and impl == "pallas":
         from repro.kernels import ops as K           # deferred: no cycle
-        interp = K.default_interpret() if interpret is None else interpret
+        interp = K.resolve_interpret(interpret)
         bs = cache_l["k"].shape[2]
         n_virtual = seg_tables.shape[1] * bs
         seg_valid = jnp.arange(n_virtual)[None, :] < seg_starts[:, None]
@@ -675,12 +675,12 @@ def attn_decode_paged(q, cache_l: Dict[str, jnp.ndarray],
     ``REPRO_PAGED_ATTN`` (jnp off-TPU, pallas on TPU).
     """
     b, h, d = q.shape
-    impl = impl or default_paged_impl()
+    impl = resolve_paged_impl(impl)
     qg = q.reshape(b, cache_l["k"].shape[1], h // cache_l["k"].shape[1], d
                    ).astype(jnp.float32)
     if impl == "pallas":
         from repro.kernels import ops as K           # deferred: no cycle
-        interp = K.default_interpret() if interpret is None else interpret
+        interp = K.resolve_interpret(interpret)
         o, l, m = K.paged_flash_decode(
             q.astype(jnp.float32), cache_l["k"], cache_l["v"], block_tables,
             valid, cache_l.get("k_scale"), cache_l.get("v_scale"),
@@ -719,6 +719,21 @@ def default_paged_impl() -> str:
         return forced
     import jax as _jax
     return "pallas" if _jax.default_backend() == "tpu" else "jnp"
+
+
+def resolve_paged_impl(impl: Optional[str] = None) -> str:
+    """The paged attention impl a call site uses: the explicit value, else
+    ``default_paged_impl()``.  Raises on a TPU backend if that comes out
+    "jnp" — the chip always reads pages through the Pallas kernels, never
+    the gather that materializes every row's cache."""
+    impl = impl or default_paged_impl()
+    import jax as _jax
+    if impl == "jnp" and _jax.default_backend() == "tpu":
+        raise RuntimeError(
+            "the jnp page gather was requested on a TPU backend (explicit "
+            "impl='jnp' or REPRO_PAGED_ATTN=jnp); unset it — the chip runs "
+            "the paged Pallas kernels")
+    return impl
 
 
 # ---------------------------------------------------------------------------
@@ -933,7 +948,7 @@ def _flash_decode_sharded(ctx, qg, k, v, valid):
         return o_glb, l_glb, m_glb
 
     from jax.sharding import PartitionSpec as P
-    return parallel.shard_map(
+    return jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(dspec), P(dspec, None, ax), P(dspec, None, ax), P(dspec, ax)),
         out_specs=(P(dspec), P(dspec), P(dspec)),

@@ -121,7 +121,7 @@ def moe_ep(params, x, cfg, ctx: parallel.ParallelContext) -> Tuple[jnp.ndarray, 
                 aux = jax.lax.pmean(aux, a)
         return out.reshape(bl, s, d).astype(dt), aux
 
-    y, aux = parallel.shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=ctx.mesh,
         in_specs=(P(), P(ax), P(ax), P(ax), P(dspec)),
         out_specs=(P(dspec), P()),

@@ -15,19 +15,6 @@ from typing import Mapping, Optional, Sequence, Tuple, Union
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
-# jax >= 0.5 promotes shard_map to the top level (kwarg ``check_vma``);
-# 0.4.x keeps it experimental (kwarg ``check_rep``).  One shim for both.
-if hasattr(jax, "shard_map"):
-    shard_map = jax.shard_map
-else:  # pragma: no cover - depends on installed jax
-    from jax.experimental.shard_map import shard_map as _shard_map_exp
-
-    def shard_map(f, *, mesh, in_specs, out_specs, **kw):
-        if "check_vma" in kw:
-            kw["check_rep"] = kw.pop("check_vma")
-        return _shard_map_exp(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, **kw)
-
 Axes = Union[None, str, Tuple[str, ...]]
 
 

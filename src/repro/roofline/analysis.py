@@ -4,6 +4,8 @@ compute term    = FLOPs / (chips * peak)
 memory term     = bytes / (chips * HBM_bw)
 collective term = collective_bytes / (chips * link_bw)
 
+with the peaks of the named ``device_kind`` (``repro.roofline.constants``).
+
 FLOPs/bytes: analytic model (primary — XLA cost_analysis does not scale
 while-loop bodies by trip count and our layer loop is a scan) with
 cost_analysis reported alongside as a cross-check.
@@ -22,7 +24,7 @@ from typing import Dict, Optional
 
 from repro.configs import InputShape, ModelConfig
 from repro.roofline import analytic
-from repro.roofline.constants import HBM_BW, ICI_LINK_BW, PEAK_FLOPS_BF16
+from repro.roofline.constants import peaks
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "s64": 8, "u64": 8,
@@ -130,15 +132,16 @@ class RooflineReport:
 
 
 def build_report(cfg: ModelConfig, shape: InputShape, mesh_name: str,
-                 chips: int, hlo_text: str,
+                 chips: int, hlo_text: str, device_kind: str,
                  cost: Optional[dict] = None,
                  memory_stats: Optional[dict] = None,
                  note: str = "") -> RooflineReport:
     est = analytic.estimate(cfg, shape)
     coll = parse_collectives(hlo_text, loop_multiplier=cfg.n_layers)
-    t_c = est.flops / (chips * PEAK_FLOPS_BF16)
-    t_m = est.bytes / (chips * HBM_BW)
-    t_x = coll.bytes_total / (chips * ICI_LINK_BW)
+    peak = peaks(device_kind)
+    t_c = est.flops / (chips * peak.flops_bf16)
+    t_m = est.bytes / (chips * peak.hbm_bw)
+    t_x = coll.bytes_total / (chips * peak.ici_link_bw)
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     dominant = max(terms, key=terms.get)
     ca_flops = cost.get("flops") if cost else None

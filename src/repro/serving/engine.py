@@ -252,7 +252,7 @@ def probe_update(pc: ProbeConfig, theta, st: ProbeState, hidden: jnp.ndarray,
         return P.features(pc, theta, phi)
 
     if probe_impl == "kernel":
-        interp = K.default_interpret() if interpret is None else interpret
+        interp = K.resolve_interpret(interpret)
 
         def _probe(_):
             zq, zk = _features()
@@ -336,7 +336,7 @@ def probe_update_spec(pc: ProbeConfig, theta, st: ProbeState,
     zk = jnp.stack(zks, axis=1)
     boundary = jnp.stack(bnds, axis=1)
     if probe_impl == "kernel":
-        interp = K.default_interpret() if interpret is None else interpret
+        interp = K.resolve_interpret(interpret)
 
         def _probe(_):
             return K.serving_probe_spec_step(
@@ -1357,6 +1357,18 @@ class ContinuousServingEngine:
         else:
             out["admission_prefill"] = self._inject._cache_size()
         return out
+
+    def lowered_step(self):
+        """The fused step lowered (not compiled) at this engine's current
+        state and an idle chunk/spec descriptor — the program ``step``
+        runs, for callers that check which kernels it holds."""
+        args = [self.params, self.theta, self.token, self.state,
+                jnp.asarray(self.pos, jnp.int32), self.st]
+        if self.chunk_tokens:
+            args.append(self._null_chunk)
+        if self.spec_tokens:
+            args.append(self._null_spec)
+        return self._step_fn.lower(*args)
 
     # ------------------------------------------------------------------
     def step(self, chunk: Optional[ChunkWork] = None,

@@ -29,10 +29,12 @@ invariant).  A gang is never split across hosts: the whole group places
 as one unit, preserving gang-admission atomicity and intra-gang page
 sharing.
 
-Hosts step concurrently through a thread pool (the jitted fused step
-releases the GIL, so simulated hosts genuinely overlap — the source of
-the fleet's throughput win at equal total KV pages); pass
-``parallel_hosts=False`` for strictly serial stepping.
+Host i is placed on local device i (round robin when there are fewer
+devices than hosts): its params, KV pool and probe state live there, so on
+a four-chip host four hosts are four one-chip replicas.  Hosts step
+concurrently through a thread pool (the jitted fused step releases the
+GIL, so hosts genuinely overlap); pass ``parallel_hosts=False`` for
+strictly serial stepping.
 
 The router speaks the same ``submit()`` / ``step()`` / ``drain()`` /
 ``run()`` protocol as ``OrcaScheduler``, so ``repro.api.serve_requests``
@@ -46,6 +48,7 @@ import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.serving.config import ServeConfig
@@ -118,14 +121,19 @@ class FleetRouter:
             DraftCache(capacity=cfg.draft_cache_size)
             if spec_on and cfg.draft_cache_size
             and getattr(model, "self_draft", False) else None)
+        # host i lives on local device i (round robin when hosts outnumber
+        # devices): its own params, KV pool and probe state, so each host's
+        # jitted step runs on its own chip
+        devices = jax.local_devices()
         self.hosts: List[OrcaScheduler] = []
-        for share in shares:
+        for i, share in enumerate(shares):
             host_cfg = dataclasses.replace(
                 cfg, n_hosts=1, num_blocks=share,
                 policy=_clone_policy(cfg.policy))
             self.hosts.append(OrcaScheduler(
                 model, params, probe_config, theta, host_cfg,
-                draft_cache=self.draft_cache))
+                draft_cache=self.draft_cache,
+                device=devices[i % len(devices)]))
         # mirror the resolved single-host attributes callers introspect
         h0 = self.hosts[0]
         self.n_slots = h0.n_slots            # PER HOST

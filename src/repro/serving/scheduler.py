@@ -84,6 +84,7 @@ import warnings
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple, Union
 
+import jax
 import numpy as np
 
 from repro.core.calibrator import GroupCalibrator
@@ -165,7 +166,14 @@ class OrcaScheduler:
                  preemption: bool = _UNSET,
                  spec_tokens: Optional[int] = _UNSET,
                  spec_tree: Optional[str] = _UNSET,
-                 draft_cache: Optional[DraftCache] = None):
+                 draft_cache: Optional[DraftCache] = None,
+                 device: Optional[jax.Device] = None):
+        # ``device``: the accelerator this scheduler's engine lives on —
+        # params, KV cache, page pool and probe state (None: JAX's default
+        # device).  The fleet router gives each host its own.
+        self.device = device
+        if device is not None:
+            params, theta = jax.device_put((params, theta), device)
         self.model, self.params, self.pc, self.theta, self.cfg = \
             model, params, pc, theta, cfg
         n_slots = int(_pick(n_slots, cfg.n_slots))
@@ -408,28 +416,31 @@ class OrcaScheduler:
                 if self._engine is not None and self._resident():
                     self._refuse_rebuild("an engine cache_len",
                                          self._engine.cache_len, cache_len)
-                self._engine = ContinuousServingEngine(
-                    self.model, self.params, self.pc, self.theta, self.cfg,
-                    self.n_slots, cache_len, probe_impl=self.probe_impl,
-                    interpret=self.interpret, paged=device_paged,
-                    block_size=self.block_size, num_blocks=num_blocks,
-                    chunk_tokens=self.chunk_tokens,
-                    pack_max=self.pack_max,
-                    spec_tokens=(None if self.spec_tree
-                                 else self.spec_tokens),
-                    spec_tree=self.spec_tree)
+                with jax.default_device(self.device):
+                    self._engine = ContinuousServingEngine(
+                        self.model, self.params, self.pc, self.theta,
+                        self.cfg, self.n_slots, cache_len,
+                        probe_impl=self.probe_impl,
+                        interpret=self.interpret, paged=device_paged,
+                        block_size=self.block_size, num_blocks=num_blocks,
+                        chunk_tokens=self.chunk_tokens,
+                        pack_max=self.pack_max,
+                        spec_tokens=(None if self.spec_tree
+                                     else self.spec_tokens),
+                        spec_tree=self.spec_tree)
         elif self._engine is None or self._engine.cache_len < cache_len:
             if self._engine is not None and self._resident():
                 self._refuse_rebuild("an engine cache_len",
                                      self._engine.cache_len, cache_len)
-            self._engine = ContinuousServingEngine(
-                self.model, self.params, self.pc, self.theta, self.cfg,
-                self.n_slots, cache_len, probe_impl=self.probe_impl,
-                interpret=self.interpret, chunk_tokens=self.chunk_tokens,
-                pack_max=self.pack_max,
-                spec_tokens=(None if self.spec_tree
-                             else self.spec_tokens),
-                spec_tree=self.spec_tree)
+            with jax.default_device(self.device):
+                self._engine = ContinuousServingEngine(
+                    self.model, self.params, self.pc, self.theta, self.cfg,
+                    self.n_slots, cache_len, probe_impl=self.probe_impl,
+                    interpret=self.interpret,
+                    chunk_tokens=self.chunk_tokens, pack_max=self.pack_max,
+                    spec_tokens=(None if self.spec_tree
+                                 else self.spec_tokens),
+                    spec_tree=self.spec_tree)
         return self._engine
 
     # ------------------------------------------------------------------

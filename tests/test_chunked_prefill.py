@@ -62,10 +62,16 @@ def test_chunked_prefill_cache_matches_full_prefill(small_model, chunk):
     state = chunked_prefill(model, params, {"tokens": toks}, cache_len,
                             chunk_tokens=chunk)
     for key in full:
+        want = np.asarray(full[key][:, :, :, :S]).astype(np.float32)
+        # layer 0 matches bit for bit; layer 1 reads attention whose chunked
+        # softmax spans [cache | chunk] columns, so XLA's CPU reductions run
+        # in another order than the one-shot prefill's: ~10 float32 ulps of
+        # the largest entry (|K/V| ~ 40 here), which a fixed 2e-5 floor
+        # (5 ulps at that scale) does not cover
+        atol = max(2e-5, 16 * float(np.spacing(np.abs(want).max())))
         np.testing.assert_allclose(
-            np.asarray(full[key][:, :, :, :S]).astype(np.float32),
-            np.asarray(state[key][:, :, :, :S]).astype(np.float32),
-            rtol=2e-5, atol=2e-5, err_msg=key)
+            want, np.asarray(state[key][:, :, :, :S]).astype(np.float32),
+            rtol=2e-5, atol=atol, err_msg=key)
     # padding beyond the prompt is DROPPED, not written
     assert np.abs(np.asarray(state["k"][:, :, :, S:]).astype(
         np.float32)).max(initial=0.0) == 0.0

@@ -30,6 +30,7 @@ def run_in_devices(code: str, n_devices: int = 8, timeout: int = 600):
 def test_moe_ep_matches_dense_oracle():
     run_in_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_test_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.models import moe as M
@@ -38,7 +39,7 @@ def test_moe_ep_matches_dense_oracle():
     import dataclasses
 
     cfg = get_config("granite-moe-1b-a400m").reduced()   # 4 experts top-2
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh((2, 4))
     params = init_params(M.moe_decls(cfg), jax.random.PRNGKey(0))
     x = jax.random.normal(jax.random.PRNGKey(1), (4, 16, cfg.d_model),
                           jnp.float32)
@@ -69,10 +70,11 @@ def test_moe_ep_matches_dense_oracle():
 def test_flash_decode_sharded_matches_core():
     run_in_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_test_mesh
     from repro.models.attention import _decode_core, _flash_decode_sharded
     from repro.parallel import ParallelContext
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh((2, 4))
     ctx = ParallelContext(mesh=mesh, rules={"batch": "data"},
                           data_axes=("data",), model_axis="model",
                           flash_decode=True)
@@ -101,6 +103,7 @@ def test_small_mesh_train_and_decode_lowering():
     one train step + one serve step of a reduced arch, sharded."""
     run_in_devices("""
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_test_mesh
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config, InputShape
     from repro.launch import shardings as SH
@@ -111,7 +114,7 @@ def test_small_mesh_train_and_decode_lowering():
 
     cfg = get_config("granite-moe-1b-a400m").reduced()
     model = build(cfg)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_test_mesh((2, 4))
     shape = InputShape("t", 64, 4, "train")
     ctx = SH.make_context(cfg, mesh, shape, multi_pod=False)
     ctx = dataclasses.replace(ctx, attn_impl="einsum", remat=False)
@@ -151,3 +154,30 @@ def test_small_mesh_train_and_decode_lowering():
         assert np.isfinite(np.asarray(st.smoothed, np.float32)).all()
         print("sharded serve step OK")
     """)
+
+
+def test_fleet_hosts_live_on_their_own_devices():
+    """FleetRouter places host i on local device i — params, page pool and
+    probe state — and serves the same stops as one host on one device."""
+    run_in_devices("""
+    import jax, numpy as np
+    from repro.serving.replay import serve_replay
+
+    rs = np.random.RandomState(0)
+    bank = (rs.randn(8, 12, 16) * 0.3
+            + np.linspace(0, 1.2, 12)[None, :, None]).astype(np.float32)
+    theta = {"W0": np.full((16,), 0.25, np.float32), "b0": np.float32(-0.5)}
+    kw = dict(lam=0.6, burn_in=1, n_slots=2, paged=True, block_size=4)
+    one, _, _ = serve_replay(bank, theta, n_hosts=1, **kw)
+    four, _, router = serve_replay(bank, theta, n_hosts=4, **kw)
+    ids = [h.device.id for h in router.hosts]
+    assert ids == [0, 1, 2, 3], ids
+    for h in router.hosts:
+        held = {d.id for leaf in jax.tree.leaves(h._engine.state)
+                for d in leaf.devices()}
+        assert held == {h.device.id}, (held, h.device.id)
+    key = lambda rs_: [r.stop_step for r in sorted(rs_, key=lambda r: r.req_id)]
+    assert key(one) == key(four), (key(one), key(four))
+    assert any(s >= 0 for s in key(one))              # stops actually fired
+    print("fleet device placement OK", ids, key(one))
+    """, n_devices=4)

@@ -511,7 +511,8 @@ def _fuzz_round(group_size, n_slots, policy, paged, consensus_on, seed):
     def _resident_from(r):
         return r.restored_step if r.n_preempted else r.admitted_step
     for a, b in itertools.combinations(done, 2):
-        if a.slot == b.slot:
+        # slot -1: cancelled while SWAPPED, it ended owning no slot
+        if a.slot == b.slot >= 0:
             assert (a.completed_step <= _resident_from(b)
                     or b.completed_step <= _resident_from(a))
     if paged:

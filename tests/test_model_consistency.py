@@ -142,3 +142,13 @@ def test_ring_buffer_matches_full_cache_within_window():
                                    np.asarray(lr, np.float32),
                                    rtol=2e-3, atol=2e-3, err_msg=f"step {i}")
         tok = jnp.argmax(lf[:, :cfg.vocab_size], -1).astype(jnp.int32)
+
+
+def test_smoke_logit_check_passes_at_reduced_size(monkeypatch):
+    """chip_smoke.py's logits phase at the reduced config, kernels
+    interpreted: prefill + decode through the dense cache, and packed
+    chunks + paged decode through the Pallas kernels, each within the
+    stated bound of the float32 forward pass."""
+    monkeypatch.setenv("REPRO_PAGED_ATTN", "pallas")
+    import chip_smoke
+    chip_smoke.check_logits(get_config("smollm-360m").reduced(), seed=0)

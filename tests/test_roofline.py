@@ -1,8 +1,10 @@
 """Unit tests for the roofline analysis: HLO collective parsing, analytic
 FLOP/byte model, report assembly."""
 
+import pytest
+
 from repro.configs import INPUT_SHAPES, get_config
-from repro.roofline import analytic, build_report, parse_collectives
+from repro.roofline import analytic, build_report, parse_collectives, peaks
 from repro.roofline.analysis import _shape_bytes
 
 HLO = """\
@@ -79,6 +81,7 @@ def test_analytic_sliding_window_caps_decode_context():
 def test_build_report_terms_and_dominance():
     cfg = get_config("smollm-360m")
     rep = build_report(cfg, INPUT_SHAPES["train_4k"], "16x16", 256, HLO,
+                       "TPU v5 lite",
                        cost={"flops": 1e12, "bytes accessed": 1e9})
     assert rep.dominant in ("compute", "memory", "collective")
     assert rep.t_compute > 0 and rep.t_memory > 0
@@ -86,3 +89,15 @@ def test_build_report_terms_and_dominance():
     assert rep.cost_analysis_flops == 1e12
     # train is compute-bound for this config at these constants
     assert rep.dominant == "compute"
+
+
+def test_peaks_are_keyed_by_device_kind():
+    v5e = peaks("TPU v5 lite")
+    assert (v5e.flops_bf16, v5e.hbm_bw) == (197e12, 819e9)
+    assert "TPU v5e" in v5e.source
+    # a chip nobody sourced peaks for is an error, never a default
+    with pytest.raises(KeyError, match="no published peaks"):
+        peaks("cpu")
+    with pytest.raises(KeyError):
+        build_report(get_config("smollm-360m"), INPUT_SHAPES["train_4k"],
+                     "16x16", 256, HLO, "TPU v9 imaginary")

@@ -92,3 +92,70 @@ def test_dryrun_cli_skip_path():
         capture_output=True, text=True, timeout=600, env=env)
     assert proc.returncode == 0, proc.stderr
     assert '"skip"' in proc.stdout
+
+
+ROOT = os.path.dirname(SRC)
+
+
+@pytest.mark.parametrize("env", [
+    {"JAX_PLATFORMS": "cpu"},
+    {"JAX_PLATFORMS": "cpu", "REPRO_PALLAS_INTERPRET": "1"},
+    {"JAX_PLATFORMS": "cpu", "REPRO_PAGED_ATTN": "jnp"},
+])
+def test_chip_smoke_refuses_off_chip(env):
+    """chip_smoke.py never runs (or reports success) off a TPU, nor with a
+    switch that would put the off-chip kernel path on one."""
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=300,
+        env=dict(os.environ, **env))
+    assert proc.returncode != 0
+    assert '"ok"' not in proc.stdout
+    assert "refusing" in proc.stdout + proc.stderr
+
+
+def test_compile_cache_env_dir_wins(monkeypatch, tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, JAX reads it itself and the
+    launch code sets nothing."""
+    import jax
+    from repro.launch import compile_cache as CC
+    monkeypatch.setenv(CC.ENV, str(tmp_path / "env_cache"))
+    before = jax.config.jax_compilation_cache_dir
+    assert CC.use_compile_cache(tmp_path) == str(tmp_path / "env_cache")
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / ".jax_cache").exists()
+
+
+def test_compile_cache_default_is_fixed_checkout_path(monkeypatch, tmp_path):
+    """Without the variable the cache is <checkout>/.jax_cache — the same
+    path on every call, so the next process in the checkout hits it."""
+    import jax
+    from repro.launch import compile_cache as CC
+    monkeypatch.delenv(CC.ENV, raising=False)
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        path = CC.use_compile_cache(tmp_path)
+        assert path == str(tmp_path.resolve() / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        assert CC.use_compile_cache(tmp_path) == path
+        assert CC.CHECKOUT == type(CC.CHECKOUT)(ROOT).resolve()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)
+
+
+def test_chip_path_never_imports_the_cpu_dry_run():
+    """launch/dryrun.py forces 512 host devices at import; nothing that
+    chip_smoke.py imports may pull it (or benchmarks/dryrun_matrix.py) in."""
+    code = ("import sys; sys.path[:0] = [{root!r}, {src!r}]\n"
+            "import chip_smoke, repro.launch.serve, repro.launch.compile_cache\n"
+            "import repro.serving, repro.api, repro.models\n"
+            "bad = [m for m in ('repro.launch.dryrun', 'benchmarks.dryrun_matrix')"
+            " if m in sys.modules]\n"
+            "assert not bad, bad\n"
+            "import os; assert 'xla_force_host_platform_device_count' not in "
+            "os.environ.get('XLA_FLAGS', '')\n").format(root=ROOT, src=SRC)
+    env = {k: v for k, v in os.environ.items() if k != "XLA_FLAGS"}
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, timeout=300,
+                          env=dict(env, JAX_PLATFORMS="cpu"))
+    assert proc.returncode == 0, proc.stderr
