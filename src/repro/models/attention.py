@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import parallel
+from repro.models.common import scoped
 
 NEG_INF = -1e30
 
@@ -51,6 +52,7 @@ def init_cache(cfg, batch: int, length: int, n_layers: Optional[int] = None,
     return {"k": mk(shape, dt), "v": mk(shape, dt)}
 
 
+@scoped("kv_write")
 def cache_write(cache_l: Dict[str, jnp.ndarray], k: jnp.ndarray, v: jnp.ndarray,
                 slot: jnp.ndarray) -> Dict[str, jnp.ndarray]:
     """Write one token into a per-layer cache slice (B, KV, S, dh) at ``slot``."""
@@ -71,6 +73,7 @@ def cache_write(cache_l: Dict[str, jnp.ndarray], k: jnp.ndarray, v: jnp.ndarray,
     return out
 
 
+@scoped("kv_write")
 def cache_write_stacked(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
                         vs: jnp.ndarray, slot: jnp.ndarray
                         ) -> Dict[str, jnp.ndarray]:
@@ -111,6 +114,7 @@ def cache_write_stacked(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
     return out
 
 
+@scoped("decode_attention")
 def decode_valid_mask(pos: jnp.ndarray, batch: int, s_cache: int,
                       window: Optional[int] = None
                       ) -> Tuple[jnp.ndarray, jnp.ndarray]:
@@ -171,6 +175,7 @@ def init_paged_cache(cfg, num_blocks: int, block_size: int,
     return {"k": mk(shape, dt), "v": mk(shape, dt)}
 
 
+@scoped("kv_write")
 def cache_write_paged(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
                       vs: jnp.ndarray, block_tables: jnp.ndarray,
                       pos: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -217,6 +222,7 @@ def chunk_write_positions(pos_start: jnp.ndarray, chunk_len: jnp.ndarray,
                      s_cache)
 
 
+@scoped("kv_write")
 def cache_write_chunk(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
                       vs: jnp.ndarray, rows: jnp.ndarray,
                       pos_start: jnp.ndarray, chunk_len: jnp.ndarray
@@ -253,6 +259,7 @@ def cache_write_chunk(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
     return out
 
 
+@scoped("kv_write")
 def cache_write_chunk_paged(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
                             vs: jnp.ndarray, block_rows: jnp.ndarray,
                             pos_start: jnp.ndarray, chunk_len: jnp.ndarray
@@ -543,6 +550,7 @@ def attn_prefill_packed(q, k_new, v_new, cache_l: Dict[str, jnp.ndarray],
     return out.reshape(c, h, d).astype(dtype)
 
 
+@scoped("kv_write")
 def cache_write_packed(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
                        vs: jnp.ndarray, rows: jnp.ndarray,
                        wpos: jnp.ndarray) -> Dict[str, jnp.ndarray]:
@@ -575,6 +583,7 @@ def cache_write_packed(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
     return out
 
 
+@scoped("kv_write")
 def cache_write_packed_paged(cache: Dict[str, jnp.ndarray], ks: jnp.ndarray,
                              vs: jnp.ndarray, tok_tables: jnp.ndarray,
                              wpos: jnp.ndarray, valid_tok: jnp.ndarray
@@ -647,6 +656,7 @@ def copy_pages(pages: Dict[str, jnp.ndarray], src: jnp.ndarray,
     return {key: buf.at[:, dst].set(buf[:, src]) for key, buf in pages.items()}
 
 
+@scoped("decode_attention")
 def paged_valid_mask(pos: jnp.ndarray, batch: int, n_virtual: int
                      ) -> jnp.ndarray:
     """Readable virtual positions for a paged decode step: [0, pos) per row.
@@ -658,6 +668,7 @@ def paged_valid_mask(pos: jnp.ndarray, batch: int, n_virtual: int
     return jnp.arange(n_virtual)[None, :] < pos[:, None]
 
 
+@scoped("decode_attention")
 def attn_decode_paged(q, cache_l: Dict[str, jnp.ndarray],
                       block_tables: jnp.ndarray, valid: jnp.ndarray,
                       dtype, extra_kv=None, *,
@@ -910,6 +921,7 @@ def _merge_extra_kv(qg, o, l, m, extra_kv, d):
     return o, l
 
 
+@scoped("decode_attention")
 def attn_decode(q, cache_l, valid, dtype, extra_kv=None) -> jnp.ndarray:
     """q (B,H,d); cache_l per-layer dict (B,KV,S,d) READ-ONLY; valid (B,S);
     extra_kv: optional (k_new, v_new) each (B,KV,d) — the current token."""

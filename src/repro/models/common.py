@@ -8,12 +8,33 @@ derives the matching ``PartitionSpec`` tree for any sharding policy.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec
+
+# ---------------------------------------------------------------------------
+# Named scopes
+
+
+def scoped(name: str) -> Callable[[Callable], Callable]:
+    """Decorator: trace the function under ``jax.named_scope(name)``, so
+    every op it emits carries ``name`` in its metadata and a device trace
+    attributes the op to the phase that emitted it.  The model's phases
+    carry plain names (``kv_write``, ``decode_attention``, ``mlp``); the
+    serving step names its own ``orca/...``.  Metadata only: the
+    computation is unchanged."""
+    def deco(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def inner(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return inner
+    return deco
+
 
 # ---------------------------------------------------------------------------
 # Param declarations

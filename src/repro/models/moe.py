@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from repro import parallel
-from repro.models.common import Param, swiglu
+from repro.models.common import Param, scoped, swiglu
 
 
 def moe_decls(cfg) -> Dict[str, Param]:
@@ -130,6 +130,7 @@ def moe_ep(params, x, cfg, ctx: parallel.ParallelContext) -> Tuple[jnp.ndarray, 
     return y, aux
 
 
+@scoped("mlp")
 def moe_block(params, x, cfg) -> Tuple[jnp.ndarray, jnp.ndarray]:
     ctx = parallel.current_ctx()
     if ctx is not None and ctx.ep_moe:

@@ -17,7 +17,7 @@ from repro import parallel
 from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models.common import (Param, apply_norm, apply_rope, cdtype, gelu,
-                                 norm_decls, stack_decls, swiglu)
+                                 norm_decls, scoped, stack_decls, swiglu)
 
 
 # ---------------------------------------------------------------------------
@@ -83,6 +83,7 @@ def decls(cfg) -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 # Blocks
 
+@scoped("mlp")
 def mlp_apply(cfg, p, x):
     dt = x.dtype
     if cfg.mlp == "swiglu":
@@ -311,7 +312,8 @@ def _packed_chunk_core(cfg, params, tokens, state, seg, slots, starts,
                                      positions, seg, starts, chunk_mask)
         return x, kv
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], scanned))
+    with jax.named_scope("layers"):
+        x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], scanned))
     if not write:
         return state, x, ks, vs
     # ks/vs (L, KV, C, dh): one per-token write for all layers
@@ -364,8 +366,9 @@ def verify_packed_chunk(cfg, params, tokens, state, seg, slots, starts,
     state, x, _, _ = _packed_chunk_core(cfg, params, tokens, state, seg,
                                         slots, starts, lengths,
                                         block_rows=block_rows)
-    h = apply_norm(cfg, params["final_norm"], x)[0]       # (C, d)
-    logits = logits_from_hidden(cfg, params, h)
+    with jax.named_scope("lm_head"):
+        h = apply_norm(cfg, params["final_norm"], x)[0]   # (C, d)
+        logits = logits_from_hidden(cfg, params, h)
     return logits, h, state
 
 
@@ -396,8 +399,9 @@ def verify_packed_tree(cfg, params, tokens, state, seg, slots, starts,
                                       slots, starts, lengths,
                                       block_rows=block_rows, depths=depths,
                                       ancestors=ancestors, write=False)
-    h = apply_norm(cfg, params["final_norm"], x)[0]       # (C, d)
-    logits = logits_from_hidden(cfg, params, h)
+    with jax.named_scope("lm_head"):
+        h = apply_norm(cfg, params["final_norm"], x)[0]   # (C, d)
+        logits = logits_from_hidden(cfg, params, h)
     return logits, h, ks, vs
 
 
@@ -591,12 +595,14 @@ def decode_step(cfg, params, token, cache, pos, *, window: Optional[int] = None,
                                  block_tables=bt)
         return x, kv_new
 
-    x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], scanned))
+    with jax.named_scope("layers"):
+        x, (ks, vs) = jax.lax.scan(body, x, (params["layers"], scanned))
     if paged:
         new_cache = dict(attn.cache_write_paged(pages, ks, vs, bt, pos),
                          block_tables=bt)
     else:
         new_cache = attn.cache_write_stacked(cache, ks, vs, slot)
-    h = apply_norm(cfg, params["final_norm"], x[:, None, :])[:, 0]
-    logits = logits_from_hidden(cfg, params, h)
+    with jax.named_scope("lm_head"):
+        h = apply_norm(cfg, params["final_norm"], x[:, None, :])[:, 0]
+        logits = logits_from_hidden(cfg, params, h)
     return logits, h, new_cache

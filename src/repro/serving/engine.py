@@ -53,9 +53,11 @@ from repro.kernels import ref as KR
 from repro.kernels.ttt_probe import ProbeStepOut as KernelOut
 from repro.kernels.ttt_probe import SpecProbeOut, serving_probe_step
 from repro.models import attention as A
+from repro.models.common import scoped
 from repro.models.registry import Model
 from repro.serving.config import ServeConfig
 from repro.serving.kv_pool import NULL_BLOCK, blocks_needed, pad_row
+from repro.serving.tracing import StepRecorder
 
 
 class ProbeState(NamedTuple):
@@ -226,6 +228,7 @@ def chunked_prefill(model: Model, params, batch: Dict[str, jnp.ndarray],
     return state
 
 
+@scoped("orca/probe")
 def probe_update(pc: ProbeConfig, theta, st: ProbeState, hidden: jnp.ndarray,
                  lam: float, tokens_per_step: int, burn_in: int, *,
                  probe_impl: str = "kernel",
@@ -286,6 +289,7 @@ def probe_update(pc: ProbeConfig, theta, st: ProbeState, hidden: jnp.ndarray,
                       out.n_scores, out.smoothed, out.stopped, out.stop_step)
 
 
+@scoped("orca/probe")
 def probe_update_spec(pc: ProbeConfig, theta, st: ProbeState,
                       hidden_seq: jnp.ndarray, accept: jnp.ndarray,
                       lam: float, tokens_per_step: int, burn_in: int, *,
@@ -365,6 +369,20 @@ def probe_update_spec(pc: ProbeConfig, theta, st: ProbeState,
                         out.n_scores, out.smoothed, out.stopped,
                         out.stop_step)
     return new_st, out.smoothed_seq, out.n_seq
+
+
+@scoped("orca/chunk_prefill")
+def _chunk_prefill(model: Model, params, chunk: Dict[str, jnp.ndarray],
+                   cache):
+    """The unified step's packed prefill chunk, when it carries one; an
+    inactive chunk hands the cache back untouched."""
+    def run_chunk(cache):
+        return model.prefill_packed(model.cfg, params, chunk["tokens"], cache,
+                                    chunk["seg"], chunk["slots"],
+                                    chunk["starts"], chunk["lengths"],
+                                    chunk.get("rows"))
+
+    return jax.lax.cond(chunk["active"], run_chunk, lambda c: c, cache)
 
 
 # The unified ServeConfig (repro.serving.config) replaced the step-level
@@ -454,10 +472,13 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
         st = probe_update(pc, theta, st, hidden, cfg.lam,
                           cfg.tokens_per_step, cfg.burn_in,
                           probe_impl=probe_impl, interpret=interpret)
-        nxt = jnp.argmax(logits[:, :mcfg.vocab_size], axis=-1).astype(jnp.int32)
-        # the step on which the stop FIRES still emits its genuinely decoded
-        # token; only already-frozen sequences repeat (no-op compute slot)
-        nxt = jnp.where(prev_stopped, token, nxt)
+        with jax.named_scope("orca/lm_head"):
+            nxt = jnp.argmax(logits[:, :mcfg.vocab_size],
+                             axis=-1).astype(jnp.int32)
+            # the step on which the stop FIRES still emits its genuinely
+            # decoded token; only already-frozen sequences repeat (no-op
+            # compute slot)
+            nxt = jnp.where(prev_stopped, token, nxt)
         return nxt, cache, st
 
     if spec_tree is not None:
@@ -481,6 +502,7 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
         par_l = jnp.asarray(par_np)
         dep_l = jnp.asarray(dep_np)
 
+        @scoped("orca/verify")
         def tree_verify(params, theta, token, cache, pos, st: ProbeState,
                         lens, drafts_in, have):
             bsz = token.shape[0]
@@ -577,6 +599,7 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
             return nxt, cache, st, extras
 
         if not chunk_tokens:
+            @scoped("orca/step")
             def tree_step(params, theta, token, cache, pos, st: ProbeState,
                           spec: Dict[str, jnp.ndarray]):
                 return tree_verify(params, theta, token, cache, pos, st,
@@ -584,18 +607,11 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
                                    spec["have"])
             return tree_step
 
+        @scoped("orca/step")
         def unified_tree_step(params, theta, token, cache, pos,
                               st: ProbeState, chunk: Dict[str, jnp.ndarray],
                               spec: Dict[str, jnp.ndarray]):
-            def run_chunk(cache):
-                return model.prefill_packed(mcfg, params, chunk["tokens"],
-                                            cache, chunk["seg"],
-                                            chunk["slots"], chunk["starts"],
-                                            chunk["lengths"],
-                                            chunk.get("rows"))
-
-            cache = jax.lax.cond(chunk["active"], run_chunk,
-                                 lambda cch: cch, cache)
+            cache = _chunk_prefill(model, params, chunk, cache)
             return tree_verify(params, theta, token, cache, pos, st,
                                spec["lens"], spec["drafts"], spec["have"])
 
@@ -608,6 +624,7 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
         assert window is None, "speculative decode has no SWA ring buffer"
         kk = int(spec_tokens)
 
+        @scoped("orca/verify")
         def spec_verify(params, theta, token, cache, pos,
                         st: ProbeState, lens, drafts_in, have):
             bsz = token.shape[0]
@@ -669,6 +686,7 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
             return nxt, cache, st, extras
 
         if not chunk_tokens:
+            @scoped("orca/step")
             def spec_step(params, theta, token, cache, pos, st: ProbeState,
                           spec: Dict[str, jnp.ndarray]):
                 return spec_verify(params, theta, token, cache, pos, st,
@@ -676,24 +694,18 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
                                    spec["have"])
             return spec_step
 
+        @scoped("orca/step")
         def unified_spec_step(params, theta, token, cache, pos,
                               st: ProbeState, chunk: Dict[str, jnp.ndarray],
                               spec: Dict[str, jnp.ndarray]):
-            def run_chunk(cache):
-                return model.prefill_packed(mcfg, params, chunk["tokens"],
-                                            cache, chunk["seg"],
-                                            chunk["slots"], chunk["starts"],
-                                            chunk["lengths"],
-                                            chunk.get("rows"))
-
-            cache = jax.lax.cond(chunk["active"], run_chunk,
-                                 lambda cch: cch, cache)
+            cache = _chunk_prefill(model, params, chunk, cache)
             return spec_verify(params, theta, token, cache, pos, st,
                                spec["lens"], spec["drafts"], spec["have"])
 
         return unified_spec_step
 
     if not chunk_tokens:
+        @scoped("orca/step")
         def serve_step(params, theta, token, cache, pos, st: ProbeState):
             return decode_probe(params, theta, token, cache, pos, st)
         return serve_step
@@ -701,18 +713,13 @@ def make_serve_step(model: Model, pc: ProbeConfig, cfg: ServeConfig,
     assert model.prefill_packed is not None, \
         f"{mcfg.name}: no packed chunked prefill for this family"
 
+    @scoped("orca/step")
     def unified_step(params, theta, token, cache, pos, st: ProbeState,
                      chunk: Dict[str, jnp.ndarray]):
-        def run_chunk(cache):
-            return model.prefill_packed(mcfg, params, chunk["tokens"], cache,
-                                        chunk["seg"], chunk["slots"],
-                                        chunk["starts"], chunk["lengths"],
-                                        chunk.get("rows"))
-
         # prefill work first, decode after: order is immaterial (the chunk
         # slot is parked, other slots never read its lane) but keeps the
         # trace linear
-        cache = jax.lax.cond(chunk["active"], run_chunk, lambda c: c, cache)
+        cache = _chunk_prefill(model, params, chunk, cache)
         return decode_probe(params, theta, token, cache, pos, st)
 
     return unified_step
@@ -972,7 +979,8 @@ class ContinuousServingEngine:
 
     The scheduler (``repro.serving.scheduler.OrcaScheduler``) owns queues,
     request lifecycles, the block pool and metrics; this class owns device
-    state only.
+    state only.  ``step`` records its host spans and device->host reads in
+    ``recorder`` (the scheduler passes its own).
     """
 
     def __init__(self, model: Model, params, pc: ProbeConfig, theta,
@@ -982,10 +990,12 @@ class ContinuousServingEngine:
                  block_size: int = 16, num_blocks: Optional[int] = None,
                  chunk_tokens: Optional[int] = None,
                  pack_max: int = 4, spec_tokens: Optional[int] = None,
-                 spec_tree: Optional[Tuple[int, int]] = None):
+                 spec_tree: Optional[Tuple[int, int]] = None,
+                 recorder: Optional[StepRecorder] = None):
         self.model, self.params, self.pc, self.theta, self.cfg = \
             model, params, pc, theta, cfg
         mcfg = model.cfg
+        self.recorder = recorder if recorder is not None else StepRecorder()
         self.paged = bool(paged)
         if self.paged:
             assert model.supports_paged, \
@@ -1370,6 +1380,12 @@ class ContinuousServingEngine:
             args.append(self._null_spec)
         return self._step_fn.lower(*args)
 
+    def compiled_step_text(self) -> str:
+        """The compiled fused step as HLO text: op names as a device trace
+        shows them, each op's ``orca/...`` scope in its metadata.  Once the
+        step has run, this finds the executable in JAX's caches."""
+        return self.lowered_step().compile().as_text()
+
     # ------------------------------------------------------------------
     def step(self, chunk: Optional[ChunkWork] = None,
              spec_lens=None, spec_drafts=None,
@@ -1387,43 +1403,62 @@ class ContinuousServingEngine:
         ``spec_drafts``/``spec_have`` inject host-side drafts (the shared
         draft cache): slots with ``have=False`` fall back to the model
         family's own drafter.  Tree engines (``spec_tree``) take drafts
-        shaped (n_slots, W, D) and lens counts NODES in [0, 1 + W*D]."""
-        pos = jnp.asarray(self.pos, jnp.int32)
-        args = [self.params, self.theta, self.token, self.state, pos,
-                self.st]
-        if self.chunk_tokens:
-            args.append(self._null_chunk if chunk is None
-                        else self._chunk_to_device(chunk))
-        else:
-            assert chunk is None, "engine built without chunk_tokens"
-        if self.spec_tokens:
-            spec = dict(self._null_spec)
-            if spec_lens is not None:
-                spec["lens"] = jnp.asarray(np.asarray(spec_lens, np.int32))
-            if spec_drafts is not None:
-                assert spec_have is not None, \
-                    "spec_drafts needs its per-slot have mask"
-                spec["drafts"] = jnp.asarray(np.asarray(spec_drafts,
-                                                        np.int32))
-                spec["have"] = jnp.asarray(np.asarray(spec_have, bool))
-            self.token, self.state, self.st, extras = self._step_fn(
-                *args, spec)
-            gen = np.asarray(extras["gen"])
+        shaped (n_slots, W, D) and lens counts NODES in [0, 1 + W*D].
+
+        Recorded as the spans ``orca.upload``, ``orca.dispatch``,
+        ``orca.wait`` and ``orca.readback`` with the step's ``reads``
+        count."""
+        with self.recorder.step():
+            return self._step(chunk, spec_lens, spec_drafts, spec_have)
+
+    def _step(self, chunk, spec_lens, spec_drafts, spec_have) -> SlotStepView:
+        rec, read = self.recorder, self._read
+        with rec.span("orca.upload"):
+            args = [self.params, self.theta, self.token, self.state,
+                    jnp.asarray(self.pos, jnp.int32), self.st]
+            if self.chunk_tokens:
+                args.append(self._null_chunk if chunk is None
+                            else self._chunk_to_device(chunk))
+            else:
+                assert chunk is None, "engine built without chunk_tokens"
+            if self.spec_tokens:
+                spec = dict(self._null_spec)
+                if spec_lens is not None:
+                    spec["lens"] = jnp.asarray(np.asarray(spec_lens,
+                                                          np.int32))
+                if spec_drafts is not None:
+                    assert spec_have is not None, \
+                        "spec_drafts needs its per-slot have mask"
+                    spec["drafts"] = jnp.asarray(np.asarray(spec_drafts,
+                                                            np.int32))
+                    spec["have"] = jnp.asarray(np.asarray(spec_have, bool))
+                args.append(spec)
+            else:
+                assert spec_lens is None and spec_drafts is None, \
+                    "engine built without spec_tokens"
+        with rec.span("orca.dispatch"):
+            out = self._step_fn(*args)
+        with rec.span("orca.wait"):
+            jax.block_until_ready(out)
+        with rec.span("orca.readback"):
+            self.token, self.state, self.st = out[:3]
+            view = SlotStepView(tokens=read(self.token),
+                                stopped=read(self.st.stopped),
+                                stop_step=read(self.st.stop_step),
+                                n_scores=read(self.st.n_scores),
+                                smoothed=read(self.st.smoothed))
+            if not self.spec_tokens:
+                self.pos = self.pos + 1
+                return view
+            extras = out[3]
+            gen = read(extras["gen"])
             self.pos = self.pos + gen
-            return SlotStepView(tokens=np.asarray(self.token),
-                                stopped=np.asarray(self.st.stopped),
-                                stop_step=np.asarray(self.st.stop_step),
-                                n_scores=np.asarray(self.st.n_scores),
-                                smoothed=np.asarray(self.st.smoothed),
-                                gen=gen, seq=np.asarray(extras["seq"]),
-                                seq_scores=np.asarray(extras["seq_scores"]),
-                                seq_n=np.asarray(extras["seq_n"]))
-        assert spec_lens is None and spec_drafts is None, \
-            "engine built without spec_tokens"
-        self.token, self.state, self.st = self._step_fn(*args)
-        self.pos = self.pos + 1
-        return SlotStepView(tokens=np.asarray(self.token),
-                            stopped=np.asarray(self.st.stopped),
-                            stop_step=np.asarray(self.st.stop_step),
-                            n_scores=np.asarray(self.st.n_scores),
-                            smoothed=np.asarray(self.st.smoothed))
+            return view._replace(gen=gen, seq=read(extras["seq"]),
+                                 seq_scores=read(extras["seq_scores"]),
+                                 seq_n=read(extras["seq_n"]))
+
+    def _read(self, x) -> np.ndarray:
+        """One blocking device->host copy of a step output, counted as the
+        step's ``reads``."""
+        self.recorder.count("reads")
+        return np.asarray(x)

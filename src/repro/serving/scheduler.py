@@ -100,6 +100,7 @@ from repro.serving.policy import (ComposeView, HostPressure,
 from repro.serving.draft_cache import DraftCache
 from repro.serving.request import (FleetMetrics, Request, RequestState,
                                    latency_stats, spec_stats)
+from repro.serving.tracing import StepRecorder
 
 
 @dataclasses.dataclass(frozen=True)
@@ -329,6 +330,8 @@ class OrcaScheduler:
         self._n_preempted = self._n_restored = self._n_spilled_blocks = 0
         self.pool: Optional[BlockPool] = None
         self._engine: Optional[ContinuousServingEngine] = None
+        # every step's host spans and counters (repro.serving.tracing)
+        self.recorder = StepRecorder()
         self._session_open = False
         self._reset_session()
 
@@ -347,12 +350,11 @@ class OrcaScheduler:
         self.groups: List[RequestGroup] = []      # consensus outcomes
         self._open_groups: List[RequestGroup] = []
         self._steps = 0
-        self._active_slot_steps = 0
-        self._total_tokens = self._n_chunks = self._n_packed = 0
-        self._peak_blocks = self._prefill_skips = self._peak_step_tokens = 0
+        self._total_tokens = 0
+        self._peak_blocks = self._prefill_skips = 0
         self._n_cancelled = self._cancel_freed = 0
         self._n_preempted = self._n_restored = self._n_spilled_blocks = 0
-        self._stalls: List[float] = []
+        self.recorder.reset()
         self._t0 = time.perf_counter()
 
     @property
@@ -427,7 +429,7 @@ class OrcaScheduler:
                         pack_max=self.pack_max,
                         spec_tokens=(None if self.spec_tree
                                      else self.spec_tokens),
-                        spec_tree=self.spec_tree)
+                        spec_tree=self.spec_tree, recorder=self.recorder)
         elif self._engine is None or self._engine.cache_len < cache_len:
             if self._engine is not None and self._resident():
                 self._refuse_rebuild("an engine cache_len",
@@ -440,7 +442,7 @@ class OrcaScheduler:
                     chunk_tokens=self.chunk_tokens, pack_max=self.pack_max,
                     spec_tokens=(None if self.spec_tree
                                  else self.spec_tokens),
-                    spec_tree=self.spec_tree)
+                    spec_tree=self.spec_tree, recorder=self.recorder)
         return self._engine
 
     # ------------------------------------------------------------------
@@ -767,14 +769,7 @@ class OrcaScheduler:
             pass
         wall = max(time.perf_counter() - self._t0, 1e-9)
         requests = list(self._requests)
-        metrics = self._metrics(requests, self._steps,
-                                self._active_slot_steps,
-                                self._total_tokens, wall,
-                                self._peak_blocks, self._prefill_skips,
-                                self._stalls, self._n_chunks,
-                                self._n_packed, self._peak_step_tokens,
-                                self.groups, self._n_cancelled,
-                                self._cancel_freed)
+        metrics = self._metrics(requests, wall)
         self._session_open = False
         return requests, metrics
 
@@ -782,18 +777,48 @@ class OrcaScheduler:
         """ONE scheduler iteration: admission -> batch composition -> the
         fused engine step -> token collection / ORCA eviction -> prefill
         bookkeeping -> consensus.  Returns False when the fleet is idle
-        (nothing queued, swapped or resident)."""
+        (nothing queued, swapped or resident).
+
+        Each iteration is one record of ``self.recorder``: the spans
+        ``orca.step`` (the step index), ``orca.admit`` (the admitted
+        request ids), ``orca.compose``, the engine's ``orca.upload`` /
+        ``orca.dispatch`` / ``orca.wait`` / ``orca.readback``,
+        ``orca.collect``, ``orca.prefill_done`` and ``orca.consensus``, and
+        the step's counters (``repro.serving.tracing.COUNTERS``)."""
         if not self.has_work:
             return False
+        rec = self.recorder
+        with rec.step():
+            with rec.span("orca.admit") as ann:
+                admitted = self._admit()
+                if admitted:
+                    ann.set_metadata(req_ids=",".join(map(str, admitted)))
+            with rec.span("orca.compose"):
+                chunk, spec_kw, draft_ctx = self._compose()
+            eng = self._engine
+            if eng.chunk_tokens:
+                view = eng.step(chunk, **spec_kw)
+            else:
+                view = eng.step(**spec_kw)
+            self._steps += 1
+            with rec.span("orca.collect"):
+                self._collect(view, spec_kw.get("spec_lens"), draft_ctx)
+            with rec.span("orca.prefill_done"):
+                self._prefill_done(chunk)
+            with rec.span("orca.consensus"):
+                self._consensus()
+        return True
+
+    def _admit(self) -> List[int]:
+        """Refill free slots before the next fused step; returns the ids of
+        the requests admitted (restores are not admissions)."""
         eng = self._engine
         chunked = bool(eng.chunk_tokens)
         waiting, swapped = self._waiting, self._swapped
         running, prefilling = self._running, self._prefilling
         plans, free = self._plans, self._free
         steps = self._steps
-        t_iter = time.perf_counter()
-
-        # admission: refill free slots before the next fused step.
+        admitted: List[int] = []
         # SWAPPED requests (preemption victims) restore FIRST — ahead
         # of every WAITING unit — and a swapped head that cannot yet
         # restore BARRIERS its own class: only strictly-more-urgent
@@ -897,6 +922,7 @@ class OrcaScheduler:
             del waiting[idx]
             for req, plan in zip(members, mplans):
                 slot = free.pop()
+                admitted.append(req.req_id)
                 req.slot, req.admitted_step = slot, steps
                 req.queue_wait_s = time.perf_counter() - self._t0
                 req.state = RequestState.PREFILL
@@ -937,22 +963,28 @@ class OrcaScheduler:
                         self._register_donor(req, plan)
                     req.state = RequestState.RUNNING
                     running[slot] = req
+        return admitted
 
-        # batch composer: every resident decode token rides this step;
-        # in spec mode each RUNNING slot additionally claims up to
-        # spec_tokens - 1 extra verify tokens (greedy in slot order,
-        # capped by its remaining decode budget) from the SAME token
-        # budget; the POLICY then sizes the prefill share of what's
-        # left, and the share is PACKED across mid-prefill residents
-        # in admission order — the tail of one prompt and the head of
-        # the next fuse into one block-diagonal chunk
-        # (pack_chunks=False: one request per chunk, PR-4's composer)
-        spec_lens = None
-        spec_drafts = spec_have = None
+    def _compose(self):
+        """Batch composition: every resident decode token rides this step;
+        in spec mode each RUNNING slot additionally claims up to
+        spec_tokens - 1 extra verify tokens (greedy in slot order, capped
+        by its remaining decode budget) from the SAME token budget; the
+        POLICY then sizes the prefill share of what's left, and the share
+        is PACKED across mid-prefill residents in admission order — the
+        tail of one prompt and the head of the next fuse into one
+        block-diagonal chunk (pack_chunks=False: one request per chunk,
+        PR-4's composer).  Returns (chunk, the engine's spec keywords,
+        each drafting slot's draft-cache context)."""
+        eng = self._engine
+        running, prefilling = self._running, self._prefilling
+        rec = self.recorder
+        spec_kw: Dict[str, object] = {}
         draft_ctx: Dict[int, List[int]] = {}
         spec_total = len(running)
         if self.spec_tokens:
             spec_lens = np.zeros((self.n_slots,), np.int32)
+            spec_drafts = spec_have = None
             # no token budget -> spec extras are bounded by block length
             # alone (n_slots * (spec_tokens - 1) can never exceed this cap)
             budget_left = (self.token_budget - len(running)
@@ -996,10 +1028,12 @@ class OrcaScheduler:
                         req.draft_hits += 1
                     else:
                         req.draft_misses += 1
+            spec_kw = dict(spec_lens=spec_lens, spec_drafts=spec_drafts,
+                           spec_have=spec_have)
         chunk = None
         if prefilling:
             share = self.policy.prefill_share(self._compose_view(
-                running, prefilling, waiting, eng))
+                running, prefilling, self._waiting, eng))
             share = min(share, eng.chunk_tokens,
                         self.token_budget - spec_total)
             segs: List[ChunkSeg] = []
@@ -1032,22 +1066,19 @@ class OrcaScheduler:
                     break
             if segs:
                 chunk = ChunkWork(segs=tuple(segs))
-                self._n_chunks += 1
-                self._n_packed += int(len(segs) >= 2)
-        self._peak_step_tokens = max(
-            self._peak_step_tokens,
-            spec_total + (chunk.total_tokens if chunk else 0))
+                rec.count("prefill_segments", len(segs))
+        rec.count("decode_rows", len(running))
+        rec.count("step_tokens",
+                  spec_total + (chunk.total_tokens if chunk else 0))
+        return chunk, spec_kw, draft_ctx
 
-        if self.spec_tokens:
-            kw = dict(spec_lens=spec_lens, spec_drafts=spec_drafts,
-                      spec_have=spec_have)
-            view = eng.step(chunk, **kw) if chunked else eng.step(**kw)
-        else:
-            view = eng.step(chunk) if chunked else eng.step()
-        steps = self._steps = self._steps + 1
-        self._active_slot_steps += len(running)
+    def _collect(self, view, spec_lens, draft_ctx: Dict[int, List[int]]
+                 ) -> None:
+        """Token collection and ORCA eviction after the fused step."""
+        eng = self._engine
+        running, free = self._running, self._free
+        steps = self._steps
         now = time.perf_counter()
-
         for slot, req in list(running.items()):
             if req.first_token_step < 0:
                 req.first_token_step = steps
@@ -1124,86 +1155,91 @@ class OrcaScheduler:
             free.append(slot)
             del running[slot]
 
-        # prefill bookkeeping AFTER token collection: every segment of
-        # the packed chunk advances; a request whose last chunk just
-        # landed decodes its first token NEXT step
-        if chunk is not None:
-            for seg in chunk.segs:
-                req = prefilling[seg.slot]
-                req.prefill_progress += seg.length
-                if req.prefill_progress >= req.prompt_len:
-                    eng.finish_prefill(
-                        seg.slot, req.inputs, req.prompt_len,
-                        block_row=(req.block_ids
-                                   if eng.paged and req.block_ids
-                                   else None))
-                    del prefilling[seg.slot]
-                    plan = plans.pop(seg.slot, None)
-                    if plan is not None:
-                        self._register_donor(req, plan)
-                    req.state = RequestState.RUNNING
-                    running[seg.slot] = req
+    def _prefill_done(self, chunk: Optional[ChunkWork]) -> None:
+        """Prefill bookkeeping AFTER token collection: every segment of
+        the packed chunk advances; a request whose last chunk just landed
+        decodes its first token NEXT step."""
+        if chunk is None:
+            return
+        eng, prefilling = self._engine, self._prefilling
+        for seg in chunk.segs:
+            req = prefilling[seg.slot]
+            req.prefill_progress += seg.length
+            if req.prefill_progress >= req.prompt_len:
+                eng.finish_prefill(
+                    seg.slot, req.inputs, req.prompt_len,
+                    block_row=(req.block_ids
+                               if eng.paged and req.block_ids
+                               else None))
+                del prefilling[seg.slot]
+                plan = self._plans.pop(seg.slot, None)
+                if plan is not None:
+                    self._register_donor(req, plan)
+                req.state = RequestState.RUNNING
+                self._running[seg.slot] = req
 
-        # consensus stop: after this step's scores landed (and ORCA
-        # evictions ran — a sample stopping at this very boundary
-        # still votes its final frozen score), each open group's
-        # calibrated vote is re-checked; the first crossing CANCELS
-        # every still-running sibling mid-flight — slot, pages and
-        # probe state return to the fleet, the unspent budget becomes
-        # group savings
-        if self._open_groups:
-            still_open: List[RequestGroup] = []
-            for grp in self._open_groups:
-                fire, ans, agr = self.consensus.decide(
-                    [r.scores for r in grp.requests],
-                    [r.answers for r in grp.requests])
-                if fire:
-                    grp.consensus_step = steps
-                    grp.consensus_index = max(
-                        len(r.scores) for r in grp.requests) - 1
-                    grp.consensus_answer = int(ans)
-                    grp.consensus_agreement = float(agr)
-                    for sib in grp.requests:
-                        if sib.done:
-                            continue
-                        if sib.state is RequestState.SWAPPED:
-                            # a spilled sibling holds no slot and no
-                            # pages (both returned at spill) — drop
-                            # its queued restore and mark it cancelled
-                            for qi, (q, _) in enumerate(swapped):
-                                if q is sib:
-                                    del swapped[qi]
-                                    break
-                            sib.steps_run = len(sib.scores)
-                            sib.stop_step = -1
-                            self._complete(sib, RequestState.CANCELLED,
-                                           steps)
-                            self._n_cancelled += 1
-                            continue
-                        slot = sib.slot
-                        eng.cancel(slot)
-                        if self.paged and sib.block_ids:
-                            self._cancel_freed += \
-                                self.pool.free(sib.block_ids)
-                        free.append(slot)
-                        running.pop(slot, None)
-                        if slot in prefilling:
-                            # cancel-mid-prefill: the row sat parked
-                            # at NULL the whole prefill, so it was
-                            # never armed; drop the deferred donor
-                            # plan with it
-                            del prefilling[slot]
-                            plans.pop(slot, None)
+    def _consensus(self) -> None:
+        """Consensus stop: after this step's scores landed (and ORCA
+        evictions ran — a sample stopping at this very boundary still
+        votes its final frozen score), each open group's calibrated vote
+        is re-checked; the first crossing CANCELS every still-running
+        sibling mid-flight — slot, pages and probe state return to the
+        fleet, the unspent budget becomes group savings."""
+        if not self._open_groups:
+            return
+        eng, swapped, free = self._engine, self._swapped, self._free
+        running, prefilling = self._running, self._prefilling
+        steps = self._steps
+        still_open: List[RequestGroup] = []
+        for grp in self._open_groups:
+            fire, ans, agr = self.consensus.decide(
+                [r.scores for r in grp.requests],
+                [r.answers for r in grp.requests])
+            if fire:
+                grp.consensus_step = steps
+                grp.consensus_index = max(
+                    len(r.scores) for r in grp.requests) - 1
+                grp.consensus_answer = int(ans)
+                grp.consensus_agreement = float(agr)
+                for sib in grp.requests:
+                    if sib.done:
+                        continue
+                    if sib.state is RequestState.SWAPPED:
+                        # a spilled sibling holds no slot and no
+                        # pages (both returned at spill) — drop
+                        # its queued restore and mark it cancelled
+                        for qi, (q, _) in enumerate(swapped):
+                            if q is sib:
+                                del swapped[qi]
+                                break
                         sib.steps_run = len(sib.scores)
                         sib.stop_step = -1
                         self._complete(sib, RequestState.CANCELLED,
                                        steps)
                         self._n_cancelled += 1
-                elif not grp.done:
-                    still_open.append(grp)
-            self._open_groups = still_open
-        self._stalls.append((time.perf_counter() - t_iter) * 1e3)
-        return True
+                        continue
+                    slot = sib.slot
+                    eng.cancel(slot)
+                    if self.paged and sib.block_ids:
+                        self._cancel_freed += \
+                            self.pool.free(sib.block_ids)
+                    free.append(slot)
+                    running.pop(slot, None)
+                    if slot in prefilling:
+                        # cancel-mid-prefill: the row sat parked
+                        # at NULL the whole prefill, so it was
+                        # never armed; drop the deferred donor
+                        # plan with it
+                        del prefilling[slot]
+                        self._plans.pop(slot, None)
+                    sib.steps_run = len(sib.scores)
+                    sib.stop_step = -1
+                    self._complete(sib, RequestState.CANCELLED,
+                                   steps)
+                    self._n_cancelled += 1
+            elif not grp.done:
+                still_open.append(grp)
+        self._open_groups = still_open
 
     # ------------------------------------------------------------------
     def pressure(self, host: int = 0) -> HostPressure:
@@ -1256,16 +1292,13 @@ class OrcaScheduler:
         req.state = state
         req.completed_step = step
 
-    def _metrics(self, requests: Sequence[Request], steps: int,
-                 active_slot_steps: int, total_tokens: int,
-                 wall: float, peak_blocks: int = 0,
-                 prefill_skips: int = 0,
-                 stalls: Optional[Sequence[float]] = None,
-                 prefill_chunks: int = 0, packed_chunks: int = 0,
-                 peak_step_tokens: int = 0,
-                 groups: Optional[Sequence[RequestGroup]] = None,
-                 n_cancelled: int = 0,
-                 cancel_freed: int = 0) -> FleetMetrics:
+    def _metrics(self, requests: Sequence[Request],
+                 wall: float) -> FleetMetrics:
+        """The session's FleetMetrics; step tallies and stall tails come
+        from ``self.recorder`` (stalls: the wall time of each step)."""
+        rec = self.recorder
+        steps = self._steps
+        active_slot_steps = rec.total("decode_rows")
         n = len(requests)
         sav = [r.savings(self.cfg.tokens_per_step, self.cfg.max_new_tokens)
                for r in requests]
@@ -1273,11 +1306,12 @@ class OrcaScheduler:
         # latency tails via the shared helper (CANCELLED excluded there;
         # the FleetRouter recomputes the same stats over the fleet union)
         ttft_p50, ttft_p99, per_class = latency_stats(list(requests))
+        stalls = rec.step_ms()
         st = np.asarray(stalls if stalls else [0.0])
         # group-level accounting: savings COUNT a cancelled sample's
         # unspent budget (the whole point of consensus cancellation)
         tps, dmn = self.cfg.tokens_per_step, self.cfg.max_new_tokens
-        real_groups = [g for g in (groups or []) if g.size >= 2]
+        real_groups = [g for g in self.groups if g.size >= 2]
         g_sav = [g.savings(tps, dmn) for g in real_groups]
         fired = [g for g in real_groups if g.decided]
         # total unspent reasoning steps across groups — what the fleet
@@ -1290,28 +1324,30 @@ class OrcaScheduler:
         # same function over the fleet union, so the two can never drift)
         return FleetMetrics(
             **spec_stats(list(requests)),
-            samples_cancelled=n_cancelled,
+            samples_cancelled=self._n_cancelled,
             consensus_groups=len(fired),
             consensus_steps=(float(np.mean([g.consensus_index
                                             for g in fired]))
                              if fired else 0.0),
             group_savings=float(sum(g_unspent)),
             group_savings_mean=float(np.mean(g_sav)) if g_sav else 0.0,
-            cancel_freed_blocks=cancel_freed,
+            cancel_freed_blocks=self._cancel_freed,
             preemptions=self._n_preempted,
             restores=self._n_restored,
             spilled_blocks=self._n_spilled_blocks,
             n_requests=n, n_slots=self.n_slots, engine_steps=steps,
             active_slot_steps=active_slot_steps, wall_time_s=wall,
-            requests_per_s=n / wall, tokens_per_s=total_tokens / wall,
+            requests_per_s=n / wall, tokens_per_s=self._total_tokens / wall,
             slot_utilization=(active_slot_steps
                               / max(steps * self.n_slots, 1)),
             mean_step_savings=float(np.mean(sav)) if sav else 0.0,
             mean_queue_steps=float(np.mean(queue)) if queue else 0.0,
             pool_blocks=self.pool.num_usable if self.pool else 0,
-            peak_blocks_in_use=peak_blocks, prefill_skips=prefill_skips,
+            peak_blocks_in_use=self._peak_blocks,
+            prefill_skips=self._prefill_skips,
             ttft_ms_p50=ttft_p50, ttft_ms_p99=ttft_p99,
             stall_ms_p50=float(np.percentile(st, 50)),
             stall_ms_p99=float(np.percentile(st, 99)),
-            prefill_chunks=prefill_chunks, packed_chunks=packed_chunks,
-            peak_step_tokens=peak_step_tokens, per_class=per_class)
+            prefill_chunks=rec.steps_with("prefill_segments", 1),
+            packed_chunks=rec.steps_with("prefill_segments", 2),
+            peak_step_tokens=rec.peak("step_tokens"), per_class=per_class)
