@@ -3,7 +3,7 @@
 widths (32 layers, d_model 960, 15/5 heads, d_head 64, d_ff 2560, vocab
 49152), weights random from ``--seed``.
 
-    python3 chip_smoke.py [--seed 0]       # one chip: phases (b) and (c)
+    python3 chip_smoke.py [--seed 0]       # one chip: phases (b) to (d)
     python3 chip_smoke.py --four-chips     # four chips: the fleet phase only
 
 Phases, in one process (the process that touches JAX holds the chip):
@@ -18,6 +18,11 @@ Phases, in one process (the process that touches JAX holds the chip):
     serve 8 requests on 4 slots through ``launch.serve``'s own code in
     three modes — dense; ``--paged --chunk-tokens 64``; ``--paged
     --spec-tree 2.2``;
+(d) the paged decode kernel alone at the reasoning benchmark cell's
+    shapes (16 rows, 253 table entries of 16 positions, caches to 4041):
+    its compiled (o, l, m) partials against a float32 reference, for d 64
+    bf16 and int8 pages, d 32 in 16- and 32-token pages, and d 80 (padded
+    to 128 lanes);
 --four-chips: serve one queue through a 4-host ``FleetRouter`` (host i on
     chip i), then through one host on one chip, and require identical
     per-request stops.
@@ -58,6 +63,21 @@ SERVE_MODES = (
     ("dense", [], 1),                                    # probe
     ("paged+chunked", ["--paged", "--chunk-tokens", "64"], 3),
     ("paged+tree", ["--paged", "--spec-tree", "2.2"], 2),
+)
+# (d) geometry and tolerance.  Each bound is on max|got - ref| over
+# max|ref| of rows with context: outputs o / l, running max m, and l.
+# Rounding reads ~1e-3 to 1e-2 on a v5e (PERF.md); a wrong lane merge
+# after the kernel read 1.29 there.
+KERNEL_ROWS, KERNEL_ENTRIES, KERNEL_TOL = 16, 253, 5e-2
+KERNEL_CASES = (
+    # name, kv heads, query heads, head dim, page dtype, page size; d 32
+    # packs four positions a lane row only where a page fills whole 8-row
+    # tiles (32-token pages), and is padded to 128 lanes at 16
+    ("d64 bf16", 5, 15, 64, "bfloat16", PAGE),
+    ("d64 int8", 5, 15, 64, "int8", PAGE),
+    ("d32 bf16", 5, 15, 32, "bfloat16", PAGE),
+    ("d32 bf16 x32", 5, 15, 32, "bfloat16", 2 * PAGE),
+    ("d80 bf16", 5, 15, 80, "bfloat16", PAGE),
 )
 FLEET_HOSTS = 4
 FLEET_FLAGS = ["--requests", "16", "--paged", "--chunk-tokens", "64"]
@@ -232,6 +252,82 @@ def serve_modes(model, params, seed: int, modes=SERVE_MODES, *,
 
 
 # ---------------------------------------------------------------------------
+# (d) the paged decode kernel's partials against a float32 reference
+
+def _decode_kernel_case(kv, h, d, dtype, bs, seed):
+    """(q, k pages, v pages, tables, valid, scales, float32 k and v pages)
+    of one case: rows at lengths 0 and 1 to 7 short of the table's end
+    (geometric), one with a hole; entries past a row's length point at the NULL page or at the
+    next row's pages."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.models.attention import quantize_kv
+    b, nb = KERNEL_ROWS, KERNEL_ENTRIES
+    keys = jax.random.split(jax.random.PRNGKey(seed), 3)
+    p = b * nb + 1
+    q = jax.random.normal(keys[0], (b, h, d), jnp.float32)
+    kf, vf = (jax.random.normal(k, (p, kv, bs, d), jnp.float32)
+              for k in keys[1:])
+    if dtype == "int8":
+        (kp, ks), (vp, vs) = quantize_kv(kf), quantize_kv(vf)
+        scales = (ks, vs)
+        kf, vf = kp.astype(jnp.float32) * ks, vp.astype(jnp.float32) * vs
+    else:
+        kp, vp = kf.astype(dtype), vf.astype(dtype)
+        kf, vf, scales = kp.astype(jnp.float32), vp.astype(jnp.float32), ()
+    rng = np.random.RandomState(seed)
+    tables = rng.permutation(np.arange(1, p)).reshape(b, nb)
+    lens = np.concatenate(
+        [[0], np.round(np.geomspace(1, nb * bs - 7, b - 1))]).astype(int)
+    valid = np.arange(nb * bs)[None] < lens[:, None]
+    valid[b - 3, 5:400] = False
+    for i in range(b):
+        dead = np.arange(-(-lens[i] // bs), nb)
+        tables[i, dead[::2]] = 0
+        tables[i, dead[1::2]] = tables[(i + 1) % b, dead[1::2]]
+    return (q, kp, vp, jnp.asarray(tables, jnp.int32), jnp.asarray(valid),
+            scales, kf, vf)
+
+
+def check_decode_kernel(seed: int) -> None:
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from repro.kernels import ref as R
+    from repro.kernels.decode_attention import paged_flash_decode
+
+    print(f"[smoke] (d) paged decode kernel: {KERNEL_ROWS} rows, "
+          f"{KERNEL_ENTRIES} table entries, compiled partials against a "
+          f"float32 reference (bound {KERNEL_TOL:g})")
+    ref_fn = jax.jit(R.paged_prefill_chunk_ref)
+    for name, kv, h, d, dtype, bs in KERNEL_CASES:
+        q, kp, vp, tables, valid, scales, kf, vf = _decode_kernel_case(
+            kv, h, d, dtype, bs, seed)
+        got = paged_flash_decode(q, kp, vp, tables, valid, *scales,
+                                 interpret=False, return_partials=True)
+        with jax.default_matmul_precision("highest"):
+            ref = ref_fn(q[:, None], kf, vf, tables, valid)
+        o, l, m = (np.asarray(x, np.float64) for x in got)
+        ro, rl, rm = (np.asarray(x, np.float64)[:, :, :, 0] for x in ref)
+        live = np.asarray(valid).any(axis=1)
+
+        def rel(a, b):
+            return float(np.abs(a[live] - b[live]).max()
+                         / np.abs(b[live]).max())
+        out = o / np.maximum(l, 1e-30)[..., None]
+        rout = ro / np.maximum(rl, 1e-30)[..., None]
+        errs = {"out": rel(out, rout), "m": rel(m, rm), "l": rel(l, rl)}
+        print(f"[smoke]   {name}: " + ", ".join(
+            f"{k} {v:.3e}" for k, v in errs.items()))
+        check(max(errs.values()) <= KERNEL_TOL,
+              f"{name} partials within {KERNEL_TOL:g} of the reference")
+        check(bool((m[~live] <= -1e29).all() and not l[~live].any()
+                   and not o[~live].any()),
+              f"{name}: a row with no context keeps m = NEG_INF, l = o = 0")
+
+
+# ---------------------------------------------------------------------------
 # --four-chips: device-placed fleet hosts against one host on one chip
 
 def four_chip_fleet(model, params, seed: int, *, base=SERVE_BASE) -> None:
@@ -334,6 +430,7 @@ def main(argv=None) -> int:
         model = build(cfg)
         serve_modes(model, model.init(jax.random.PRNGKey(args.seed)),
                     args.seed)
+        check_decode_kernel(args.seed)
     print(f"[smoke] compile cache: {cache_events['hits']} hits, "
           f"{cache_events['writes']} entries written; total wall "
           f"{time.perf_counter() - t0:.1f}s")

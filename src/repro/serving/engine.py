@@ -50,6 +50,7 @@ from repro.core import stopping as S
 from repro.core.probe import ProbeConfig
 from repro.kernels import ops as K
 from repro.kernels import ref as KR
+from repro.kernels.decode_attention import decode_block_counts
 from repro.kernels.ttt_probe import ProbeStepOut as KernelOut
 from repro.kernels.ttt_probe import SpecProbeOut, serving_probe_step
 from repro.models import attention as A
@@ -1040,6 +1041,16 @@ class ContinuousServingEngine:
         elif self.spec_tokens:
             assert model.supports_spec, \
                 f"{mcfg.name}: no speculative decode for this family"
+        # the paged decode kernel's compute blocks, counted per step from
+        # the positions the step uploads (``attn_blocks_live``,
+        # ``attn_blocks``); speculative steps decode in the verify chunk
+        self._block_counts = None
+        if (self.paged and not self.spec_tokens
+                and A.resolve_paged_impl() == "pallas"):
+            k = self.state["k"]                     # (L, P, KV, bs, d)
+            self._block_counts = functools.partial(
+                decode_block_counts, bs=self.block_size, nb=self.max_blocks,
+                n_kv=k.shape[2], d=k.shape[-1], itemsize=k.dtype.itemsize)
         st = init_probe_state(pc, theta, n_slots, mcfg.d_model)
         self.st = st._replace(stopped=jnp.ones((n_slots,), bool))
         self.token = jnp.zeros((n_slots,), jnp.int32)
@@ -1407,7 +1418,8 @@ class ContinuousServingEngine:
 
         Recorded as the spans ``orca.upload``, ``orca.dispatch``,
         ``orca.wait`` and ``orca.readback`` with the step's ``reads``
-        count."""
+        count and, where the paged decode kernel runs, its
+        ``attn_blocks_live`` and ``attn_blocks``."""
         with self.recorder.step():
             return self._step(chunk, spec_lens, spec_drafts, spec_have)
 
@@ -1416,6 +1428,10 @@ class ContinuousServingEngine:
         with rec.span("orca.upload"):
             args = [self.params, self.theta, self.token, self.state,
                     jnp.asarray(self.pos, jnp.int32), self.st]
+            if self._block_counts is not None:
+                live, launched = self._block_counts(self.pos)
+                rec.count("attn_blocks_live", live)
+                rec.count("attn_blocks", launched)
             if self.chunk_tokens:
                 args.append(self._null_chunk if chunk is None
                             else self._chunk_to_device(chunk))

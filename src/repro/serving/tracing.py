@@ -44,6 +44,10 @@ COUNTERS = (          # and what reads each
     #                      FleetMetrics.prefill_chunks, packed_chunks
     "reads",             # blocking device->host reads of the step's
     #                      outputs: the benchmark's syncs.decode
+    "attn_blocks_live",  # paged decode kernel: compute blocks per layer
+    "attn_blocks",       # that ran / that a walk of every block-table
+    #                      entry would launch, over the rows with
+    #                      context: the benchmark's attn_live_share.decode
 )
 
 

@@ -129,26 +129,93 @@ def test_flash_decode_ragged_valid():
 # ---------------------------------------------------------------------------
 # Paged flash decode (block-table gather through scalar prefetch)
 
-@pytest.mark.parametrize("b,h,kv,d,bs,p,nb", [
-    (2, 8, 8, 64, 16, 24, 6),     # MHA
-    (3, 8, 2, 64, 8, 16, 4),      # GQA, small pages
-    (1, 16, 4, 128, 32, 12, 8),   # MQA-ish, larger head
+def _paged_rows(case, b, bs, p, nb, n_pages, rng):
+    """(tables, valid) of one scenario; ``n_pages`` pages per compute
+    block, so a block holds T = n_pages * bs positions.
+
+    prefix  random tables, each row at its own length
+    edges   rows of 0, 1, T and T + 1 positions; entries past a row's
+            length point at the NULL page 0
+    holes   masks with holes: a span across a block boundary, all but the
+            last live block, the first position
+    stale   entries past a row's length point at other rows' pages or at
+            the NULL page"""
+    t = n_pages * bs
+    span = np.arange(nb * bs)
+    own = rng.permutation(np.arange(1, p))[:b * nb].reshape(b, nb)
+    if case == "prefix":
+        pos = np.array([((i + 1) * nb * bs) // (b + 1) + 1 for i in range(b)])
+        return (rng.randint(0, p, (b, nb)),
+                span[None] < pos[:, None])
+    if case == "holes":
+        valid = np.stack([span < 3 * t, (span >= 2 * t + 3) & (span < 3 * t),
+                          (span > 0) & (span < t + 5)][:b])
+        valid[0, t // 2:t + t // 2] = False
+        return own, valid
+    lens = {"edges": [0, 1, t, t + 1], "stale": [t + 3, 2 * t + 1, 5]}[case]
+    lens = np.array((lens * b)[:b])
+    used = -(-lens // bs)
+    tables = own.copy()
+    for i in range(b):
+        dead = np.arange(used[i], nb)
+        if case == "stale":
+            tables[i, dead[::2]] = own[(i + 1) % b, dead[::2]]
+            tables[i, dead[1::2]] = 0
+        else:
+            tables[i, dead] = 0
+    return tables, span[None] < lens[:, None]
+
+
+@pytest.mark.parametrize("b,h,kv,d,bs,p,nb,case", [
+    # MHA; GQA with small pages; MQA-ish with a larger head
+    pytest.param(2, 8, 8, 64, 16, 24, 6, "prefix", id="2-8-8-64-16-24-6"),
+    pytest.param(3, 8, 2, 64, 8, 16, 4, "prefix", id="3-8-2-64-8-16-4"),
+    pytest.param(1, 16, 4, 128, 32, 12, 8, "prefix", id="1-16-4-128-32-12-8"),
+    # G = 3 (15 / 5 heads), as served.  64-token pages: a compute block is
+    # 16 pages (12 in float32, by the buffer cap), and 35 entries make
+    # three blocks, the last one short
+    pytest.param(4, 15, 5, 64, 64, 141, 35, "edges", id="g3-edges"),
+    # a table of fewer entries than a block's pages: one block of them all
+    pytest.param(2, 15, 5, 64, 16, 12, 3, "stale", id="g3-nb-lt-n"),
+    pytest.param(3, 15, 5, 64, 64, 141, 35, "holes", id="g3-holes"),
+    pytest.param(3, 15, 5, 64, 64, 141, 35, "stale", id="g3-stale"),
+    # four positions per lane row (d 32), and d padded to 128 lanes (d 80)
+    pytest.param(3, 4, 2, 32, 64, 141, 35, "holes", id="d32-holes"),
+    pytest.param(4, 6, 2, 80, 64, 141, 35, "edges", id="d80-edges"),
 ])
-@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
-def test_paged_flash_decode_matches_ref(b, h, kv, d, bs, p, nb, dtype):
-    ks = jax.random.split(jax.random.PRNGKey(0), 4)
-    q = jax.random.normal(ks[0], (b, h, d)).astype(dtype)
-    k_pages = jax.random.normal(ks[1], (p, kv, bs, d)).astype(dtype)
-    v_pages = jax.random.normal(ks[2], (p, kv, bs, d)).astype(dtype)
-    tables = jax.random.randint(ks[3], (b, nb), 0, p)
-    # each row at its own length (continuous batching)
-    pos = jnp.asarray([((i + 1) * nb * bs) // (b + 1) + 1 for i in range(b)])
-    valid = jnp.arange(nb * bs)[None, :] < pos[:, None]
-    out = paged_flash_decode(q, k_pages, v_pages, tables, valid)
-    ref = R.paged_decode_ref(q, k_pages, v_pages, tables, valid)
-    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
-    np.testing.assert_allclose(np.asarray(out, np.float32),
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16, "int8"])
+def test_paged_flash_decode_matches_ref(b, h, kv, d, bs, p, nb, case, dtype):
+    """The many-page, all-heads kernel equals the gathered-pages oracle:
+    blocks past a row's last valid position are skipped, masks inside
+    live blocks stay exact, and no masked table entry reaches a result."""
+    from repro.kernels.decode_attention import decode_pages_per_block
+    from repro.models.attention import quantize_kv
+    ks = jax.random.split(jax.random.PRNGKey(0), 3)
+    pages = [jax.random.normal(k, (p, kv, bs, d)) for k in ks[1:]]
+    scales = ()
+    if dtype == "int8":
+        (k_pages, ksc), (v_pages, vsc) = map(quantize_kv, pages)
+        scales, tol = (ksc, vsc), 2e-4
+    else:
+        k_pages, v_pages = (x.astype(dtype) for x in pages)
+        tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    q = jax.random.normal(ks[0], (b, h, d)).astype(
+        jnp.float32 if dtype == "int8" else dtype)
+    itemsize = jnp.dtype(jnp.int8 if dtype == "int8" else dtype).itemsize
+    n_pages = decode_pages_per_block(bs, nb, kv, d, itemsize)
+    tables, valid = _paged_rows(case, b, bs, p, nb, n_pages,
+                                np.random.RandomState(nb))
+    tables, valid = jnp.asarray(tables, jnp.int32), jnp.asarray(valid)
+    o, l, m = paged_flash_decode(q, k_pages, v_pages, tables, valid, *scales,
+                                 return_partials=True)
+    out = (o / jnp.maximum(l, 1e-30)[..., None]).reshape(b, h, d)
+    ref = R.paged_decode_ref(q, k_pages, v_pages, tables, valid, *scales)
+    np.testing.assert_allclose(np.asarray(out.astype(q.dtype), np.float32),
                                np.asarray(ref, np.float32), rtol=tol, atol=tol)
+    # a row with no valid position keeps m = NEG_INF, l = 0, o = 0
+    empty = ~np.asarray(valid).any(axis=1)
+    assert (np.asarray(m)[empty] <= -1e29).all()
+    assert not np.asarray(l)[empty].any() and not np.asarray(o)[empty].any()
 
 
 def test_paged_flash_decode_int8_kv():

@@ -97,9 +97,8 @@ def test_serving_probe_spec_step_compiles(sds, widths):
                                                        interpret=False))
 
 
-def _pool(sds, kv, d, kv_dtype):
-    nb = CACHE // BS
-    pages = SLOTS * nb + 1                      # + the NULL page
+def _pool(sds, kv, d, kv_dtype, slots=SLOTS, nb=CACHE // BS):
+    pages = slots * nb + 1                      # + the NULL page
     dt = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
     kp = sds((pages, kv, BS, d), dt)
     scales = ((sds((pages, kv, BS, 1), jnp.float32),) * 2
@@ -109,12 +108,36 @@ def _pool(sds, kv, d, kv_dtype):
 
 @pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
 def test_paged_flash_decode_compiles(sds, widths, kv_dtype):
+    """At the serving shapes above and at the reasoning benchmark cell's:
+    16 rows, 253 block-table entries (caches to 4042 positions)."""
     h, kv, d, _ = widths
-    nb, kp, (ks, vs) = _pool(sds, kv, d, kv_dtype)
+    for slots, nb in ((SLOTS, CACHE // BS), (16, 253)):
+        nb, kp, (ks, vs) = _pool(sds, kv, d, kv_dtype, slots, nb)
+        lowered = paged_flash_decode.lower(
+            sds((slots, h, d), jnp.float32), kp, kp,
+            sds((slots, nb), jnp.int32), sds((slots, nb * BS), jnp.bool_),
+            ks, vs, interpret=False, return_partials=True)
+        _compiled_has_kernel(lowered)
+
+
+@pytest.mark.parametrize("d,bs", [(32, 8), (32, 16), (32, 32), (64, 8),
+                                  (80, 16), (128, 16)])
+@pytest.mark.parametrize("kv_dtype", ["bfloat16", "int8"])
+def test_paged_flash_decode_compiles_at_other_head_dims(sds, d, bs,
+                                                        kv_dtype):
+    """Every lane layout of a page: 128 / d positions a lane row where the
+    page fills whole 8-row tiles (d 32 at 32-token pages, d 64 at 16),
+    else d padded to 128 lanes.  Packing d 32 into 16-token pages made a
+    4-row DMA slice, which Mosaic refuses."""
+    slots, nb = 4, 40
+    dt = jnp.int8 if kv_dtype == "int8" else jnp.bfloat16
+    kp = sds((slots * nb + 1, 5, bs, d), dt)
+    scales = ((sds((slots * nb + 1, 5, bs, 1), jnp.float32),) * 2
+              if kv_dtype == "int8" else ())
     lowered = paged_flash_decode.lower(
-        sds((SLOTS, h, d), jnp.float32), kp, kp,
-        sds((SLOTS, nb), jnp.int32), sds((SLOTS, nb * BS), jnp.bool_),
-        ks, vs, interpret=False, return_partials=True)
+        sds((slots, 15, d), jnp.float32), kp, kp,
+        sds((slots, nb), jnp.int32), sds((slots, nb * bs), jnp.bool_),
+        *scales, interpret=False, return_partials=True)
     _compiled_has_kernel(lowered)
 
 

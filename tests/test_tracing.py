@@ -214,6 +214,36 @@ def test_bare_engine_step_records_its_own_step(small_model):
         "orca.upload", "orca.dispatch", "orca.wait", "orca.readback"]
 
 
+def test_decode_blocks_are_counted_from_the_uploaded_positions(
+        small_model, monkeypatch):
+    """``attn_blocks_live`` / ``attn_blocks``: the paged decode kernel's
+    compute blocks per layer over the rows with context, counted on the
+    host from the positions the step uploads — no read more."""
+    from repro.kernels.decode_attention import decode_pages_per_block
+    monkeypatch.setenv("REPRO_PAGED_ATTN", "pallas")
+    model, params = small_model
+    pc = ProbeConfig(d_phi=model.cfg.d_model, smooth_window=2)
+    theta = init_outer(pc, jax.random.PRNGKey(1))
+    cfg = ServeConfig(tokens_per_step=2, max_new_tokens=8, lam=0.6,
+                      burn_in=1)
+    eng = ContinuousServingEngine(model, params, pc, theta, cfg, 4, 2048,
+                                  paged=True, block_size=128)
+    k = eng.state["k"]
+    assert decode_pages_per_block(128, 16, k.shape[2], k.shape[-1],
+                                  k.dtype.itemsize) == 8
+    eng.pos = np.array([0, 1, 1024, 1025], np.int32)
+    eng.step()
+    eng.step()
+    first, second = eng.recorder.records
+    # 16 table entries of 128 positions: 2 blocks of 1024 per row.  Rows
+    # at 1, 1024 and 1025 run 1, 1 and 2 of them; the row at 0 runs none.
+    assert first.counts == {"reads": 5, "attn_blocks_live": 4,
+                            "attn_blocks": 6}
+    # every row moved one position on: 1, 2, 1025, 1026
+    assert second.counts == {"reads": 5, "attn_blocks_live": 6,
+                             "attn_blocks": 8}
+
+
 # ---------------------------------------------------------------------------
 # the device half: named scopes in the compiled step
 
