@@ -2,7 +2,8 @@
 
 Once the window has closed and the program's state is freed, a sample of
 the requests it served, drawn from the seed with the longest in it, is run
-through the plain float32 reference (``bench.reference``) from the same
+through the plain float32 reference of the configuration's family
+(``bench.families``, run by ``bench.reference.run_rows``) from the same
 weights, made again from the seed:
 
 * ``logit_gap`` — over every served token, the widest gap by which the
@@ -41,6 +42,7 @@ from typing import Dict, Optional
 import numpy as np
 
 from bench import probe_ref as PR
+from bench import reference as R
 
 READINGS = ("logit_gap", "mean_gap", "flip_share", "score_gap", "score_mean",
             "stop_mismatch")
@@ -83,17 +85,16 @@ def padded(n: int, block: int) -> int:
 
 
 # what stands in the program's place: the program itself, the control (the
-# reference one precision below: int8 linear layers, bfloat16 probe), or the
+# family's reference one precision below, the probe in bfloat16), or the
 # probe fault (the reference with the probe's fast weights left unchanged)
 VARIANTS = ("program", "control", "probe_frozen")
 
 
-def compare(cfg: dict, weights, picked, probe: Probe, t_pad: int, *,
-            variant: str = "program", score_limit: float = 0.0
+def compare(family, cfg: dict, weights, picked, probe: Probe, t_pad: int,
+            *, variant: str = "program", score_limit: float = 0.0
             ) -> Dict[str, float]:
-    """Readings of ``variant``'s served tokens and probe scores against the
-    float32 reference and the float64 reference probe."""
-    from bench import reference as R
+    """Readings of ``variant``'s served tokens and probe scores against
+    ``family``'s float32 reference and the float64 reference probe."""
     if variant not in VARIANTS:
         raise ValueError(f"unknown variant {variant!r}")
     gaps, score_diffs, ref_means = [], [], []
@@ -104,7 +105,7 @@ def compare(cfg: dict, weights, picked, probe: Probe, t_pad: int, *,
         seq = np.zeros((t_pad,), np.int32)
         seq[:p] = s.context
         seq[p + 1:p + n] = s.tokens[:-1]          # seq[p] is the token 0
-        h_ref, lg_ref = R.run_rows(cfg, weights, seq, p, n)
+        h_ref, lg_ref = R.run_rows(family, cfg, weights, seq, p, n)
         lg_ref = lg_ref.astype(np.float64)
         best = lg_ref.max(axis=1)
         ref_scores = PR.probe_scores(h_ref, probe.w0, probe.b0,
@@ -113,7 +114,8 @@ def compare(cfg: dict, weights, picked, probe: Probe, t_pad: int, *,
         args = (probe.w0, probe.b0, probe.eta, probe.tokens_per_step,
                 probe.window)
         if variant == "control":
-            h_alt, lg_alt = R.run_rows(cfg, weights, seq, p, n, low=True)
+            h_alt, lg_alt = R.run_rows(family, cfg, weights, seq, p, n,
+                                      low=True)
             chosen = lg_alt.argmax(axis=1)
             got = PR.probe_scores(h_alt, *args, low=True)
         elif variant == "probe_frozen":

@@ -6,10 +6,11 @@ control's and the probe fault's, on several seeds in one process.
 For each seed it makes one whole run of the cell (set-up, window, sample)
 and then reads the same sample three times against the float32 reference:
 as the program served it; with the control in the program's place, the
-reference one precision below the configuration (int8 weights and
-activations in every linear layer of the model, the probe's state in
-bfloat16 below its float32); and with the probe fault, the reference with
-the probe's fast weights left unchanged.  One JSON line per seed, each
+family's reference one precision below the configuration (for
+``bench.families.llama`` int8 weights and activations in every linear
+layer of the model), the probe's state in bfloat16 below its float32; and
+with the probe fault, the reference with the probe's fast weights left
+unchanged.  One JSON line per seed, each
 reading with its verdict under the cell's limits (``bench/limits``): the
 program's has to be correct, the control's and the fault's not.  The
 benchmark's own runs never run this.

@@ -1,6 +1,7 @@
 """Kernel ``paged_flash_decode``: least time the chip needs for the decode
-attention the traced window served (valid cached positions only) over the
-kernel's device time in the trace.  Nothing when the kernel did not run."""
+attention the traced window served (valid cached positions only, counted by
+the configuration's family, ``ctx.family``) over the kernel's device time
+in the trace.  Nothing when the kernel did not run."""
 from bench import roofline as RF
 
 KERNEL = "paged_flash_decode"
@@ -14,7 +15,7 @@ def read(ctx):
         return None
     flops = nbytes = 0.0
     for s in ctx.window.steps:
-        f, b = RF.decode_attention_work(ctx.cfg, s.decode_cached)
+        f, b = ctx.family.decode_attention_work(ctx.cfg, s.decode_cached)
         flops, nbytes = flops + f, nbytes + b
     if flops == 0 and nbytes == 0:
         return None

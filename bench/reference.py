@@ -1,26 +1,25 @@
-"""The plain reference: a float32 forward pass written from the published
-architecture, with no cache, no kernels and no batching of requests.
+"""The plain reference: float32 forward passes written from the published
+architectures, with no cache, no kernels and no batching of requests.
 
-It imports nothing of the program.  It covers the decoder families of the
-configurations under ``bench/configs``: pre-norm RMSNorm blocks, rotary
-position embeddings (rotate-half), grouped-query causal attention, a SwiGLU
-FFN or a softmax top-k mixture of SwiGLU experts with renormalised gates,
-tied embeddings.  Every matmul runs at ``Precision.HIGHEST``, so float32 on
-the TPU is float32 and not one bfloat16 pass.
+It imports nothing of the program.  This module holds what every family's
+pass is built from (``bench.families``): float32 matmuls, their int8
+control, RMSNorm, rotary embeddings (rotate-half) and causal grouped-query
+attention; and ``run_rows``, which runs a family's pass over one request.
+Every matmul runs at ``Precision.HIGHEST``, so float32 on the TPU is float32
+and not one bfloat16 pass.
 
-``low=True`` is the control: the same pass with every linear layer's
-weights (per output channel) and inputs (per token) rounded to symmetric
-int8 (W8A8), the step below the configuration's bfloat16 that would tempt a
-later PR.  A comparison that cannot tell it from the program is not tight
-enough.
+``low=True`` is the control: the pass one precision below the
+configuration's, the step that would tempt a later PR (for a bfloat16
+configuration, ``_mm`` rounds weights per output channel and inputs per
+token to symmetric int8).  A comparison that cannot tell it from the
+program is not tight enough.
 
-The pass runs one layer at a time under ``lax.scan`` and attention in
+A family's pass runs one layer at a time under ``lax.scan`` and attention in
 blocks of query rows, so a whole long context fits beside nothing else.
 """
 from __future__ import annotations
 
 import functools
-from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -83,91 +82,51 @@ def _attention(q, k, v):
     return out.reshape(t, h * dh)
 
 
-def _swiglu(x, wg, wu, wd, low):
-    return _mm(jax.nn.silu(_mm(x, wg, low)) * _mm(x, wu, low), wd, low)
+_DICT, _LIST = "{}", "[]"
 
 
-def _moe(cfg, lw, x, low):
-    """Softmax over all experts, top-k, gates renormalised over the k; the
-    output is the gate-weighted sum of the k experts' SwiGLU FFNs."""
-    moe = cfg["moe"]
-    e = moe["n_experts"]
-    logits = jnp.einsum("td,de->te", x, lw["router"], precision=HI)
-    probs = jax.nn.softmax(logits, -1)
-    top, idx = jax.lax.top_k(probs, moe["top_k"])
-    top = top / jnp.sum(top, -1, keepdims=True)
-    gate = jnp.sum(jax.nn.one_hot(idx, e, dtype=jnp.float32)
-                   * top[..., None], axis=1)                       # (T, E)
-
-    def expert(acc, ew):
-        wg, wu, wd, ge = ew
-        return acc + ge[:, None] * _swiglu(x, wg, wu, wd, low), None
-
-    y, _ = jax.lax.scan(expert, jnp.zeros_like(x),
-                        (lw["w_gate"], lw["w_up"], lw["w_down"], gate.T))
-    return y
+def freeze(value):
+    """A hashable form of a configuration (for the jit cache): dicts and
+    lists at any depth become tagged tuples, so ``thaw`` rebuilds them as
+    they were; a value of any other type than a JSON scalar raises."""
+    if isinstance(value, dict):
+        return (_DICT, tuple(sorted((k, freeze(v)) for k, v in value.items())))
+    if isinstance(value, (list, tuple)):
+        return (_LIST, tuple(freeze(v) for v in value))
+    if value is None or isinstance(value, (bool, int, float, str)):
+        return value
+    raise TypeError(f"cannot freeze {value!r} of type {type(value).__name__}")
 
 
-def hidden_states(cfg: dict, w: Dict[str, jax.Array], tokens, *,
-                  low: bool = False):
-    """Final-normed hidden states (T, d) of ``tokens`` (T,), T a multiple
-    of ``Q_BLOCK``; causal, so padding at the end changes nothing before
-    it."""
-    t = tokens.shape[0]
-    H, KV, dh = cfg["n_heads"], cfg["n_kv_heads"], cfg["d_head"]
-    theta = float(cfg.get("rope_theta", 10000.0))
-    pos = jnp.arange(t)
-    x = w["embed"][tokens]
-    layer_keys = ["ln1", "ln2", "wq", "wk", "wv", "wo", "w_gate", "w_up",
-                  "w_down"] + (["router"] if cfg.get("moe") else [])
-
-    def layer(x, lw):
-        h = _rms(x, lw["ln1"])
-        q = _rope(_mm(h, lw["wq"], low).reshape(t, H, dh), pos, theta)
-        k = _rope(_mm(h, lw["wk"], low).reshape(t, KV, dh), pos, theta)
-        v = _mm(h, lw["wv"], low).reshape(t, KV, dh)
-        x = x + _mm(_attention(q, k, v), lw["wo"], low)
-        h = _rms(x, lw["ln2"])
-        if cfg.get("moe"):
-            m = _moe(cfg, lw, h, low)
-        else:
-            m = _swiglu(h, lw["w_gate"], lw["w_up"], lw["w_down"], low)
-        return x + m, None
-
-    x, _ = jax.lax.scan(layer, x, {k: w[k] for k in layer_keys})
-    return _rms(x, w["final_norm"])
-
-
-def logits(w: Dict[str, jax.Array], h):
-    """LM head (tied embedding) over the real vocabulary: (n, d) -> (n, V)."""
-    return jnp.einsum("nd,vd->nv", h, w["embed"], precision=HI)
-
-
-def freeze(cfg: dict):
-    """A hashable form of a configuration dict (for the jit cache)."""
-    return tuple(sorted((k, tuple(sorted(v.items())) if isinstance(v, dict)
-                         else v) for k, v in cfg.items()
-                        if isinstance(v, (int, float, str, dict))))
+def thaw(frozen):
+    """The configuration ``freeze`` was given."""
+    if not isinstance(frozen, tuple):
+        return frozen
+    tag, body = frozen
+    if tag == _DICT:
+        return {k: thaw(v) for k, v in body}
+    return [thaw(v) for v in body]
 
 
 @functools.lru_cache(maxsize=16)
-def _rows_fn(cfg_key, low: bool, rows: int):
-    cfg = {k: dict(v) if isinstance(v, tuple) else v for k, v in cfg_key}
+def _rows_fn(family, cfg_key, low: bool, rows: int):
+    cfg = thaw(cfg_key)
 
     def fn(w, tokens, start):
-        h = hidden_states(cfg, w, tokens, low=low)
+        h = family.hidden_states(cfg, w, tokens, low=low)
         idx = start + jnp.arange(rows)
         hr = jnp.take(h, idx, axis=0, mode="clip")
-        return hr, logits(w, hr)
+        return hr, family.logits(w, hr)
     return jax.jit(fn)
 
 
-def run_rows(cfg: dict, w, tokens, start: int, n: int, *,
+def run_rows(family, cfg: dict, w, tokens, start: int, n: int, *,
              low: bool = False):
     """Hidden states and logits of positions [start, start + n) of
-    ``tokens``, as float32 numpy.  One program per power-of-two row count,
-    so the shapes a run meets compile once and then come from the cache."""
+    ``tokens`` under ``family``'s pass, as float32 numpy.  One program per
+    power-of-two row count, so the shapes a run meets compile once and then
+    come from the cache."""
     rows = max(64, 1 << (max(n, 1) - 1).bit_length())
-    h, lg = _rows_fn(freeze(cfg), bool(low), rows)(
+    h, lg = _rows_fn(family, freeze(cfg), bool(low), rows)(
         w, jnp.asarray(tokens, jnp.int32), jnp.int32(start))
     return np.asarray(h)[:n], np.asarray(lg)[:n]

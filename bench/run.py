@@ -4,8 +4,9 @@
         --seconds S --trace 0|1
 
 The cell is found by name in ``BENCHMARK.json``: its configuration file
-under ``bench/configs``, its traffic mix under ``bench/traffic``, its limits
-under ``bench/limits/<workload>.json`` and a reader under
+under ``bench/configs``, which names its architecture's module under
+``family`` (``bench.families``), its traffic mix under ``bench/traffic``,
+its limits under ``bench/limits/<workload>.json`` and a reader under
 ``bench/metrics/<metric>.py`` for each of its per-layer metrics.
 
 A run refuses (exit 1, no result) unless JAX's devices are TPUs, as many as
@@ -13,10 +14,10 @@ the cell asks for.  It makes the weights on the chip from the seed,
 calibrates the probe on a seeded synthetic bank, warms the loop up, then
 measures for ``--seconds``: the end-to-end metrics with ``--trace 0``; with
 ``--trace 1`` the per-layer metrics, from the same window run under the
-profiler.  Then it frees the program's state and holds a
-sample of what was served to the float32 reference.  The last lines on
-standard error are the numbers compared, each beside its limit; the last
-line on standard output is the result as one JSON object.
+profiler.  Then it frees the program's state and holds a sample of what
+was served to the family's float32 reference.  The last lines on standard
+error are the numbers compared, each beside its limit; the last line on
+standard output is the result as one JSON object.
 """
 from __future__ import annotations
 
@@ -25,6 +26,7 @@ import time
 _T_IMPORT = time.perf_counter()
 
 import argparse  # noqa: E402
+import dataclasses  # noqa: E402
 import gc  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -32,6 +34,7 @@ import os  # noqa: E402
 import shutil  # noqa: E402
 import sys  # noqa: E402
 import types  # noqa: E402
+import typing  # noqa: E402
 from pathlib import Path  # noqa: E402
 from typing import Callable, Dict, Optional  # noqa: E402
 
@@ -122,11 +125,29 @@ def use_compile_cache() -> str:
 
 
 def model_config(conf: dict):
-    from repro.configs.base import ModelConfig, MoEConfig
-    fields = dict(conf["program"])
-    if "moe" in fields:
-        fields["moe"] = MoEConfig(**fields["moe"])
-    return ModelConfig(**fields)
+    """The program's ``ModelConfig`` from the file's ``program`` group,
+    handed over whole; hashable, as the program needs it."""
+    from repro.configs.base import ModelConfig
+    return _build(ModelConfig, conf["program"])
+
+
+def _build(cls, fields: dict):
+    """``cls(**fields)`` with every JSON list as a tuple and every
+    dataclass-typed field (``moe``, ``ssm``, ``frontend``) built from its
+    dict."""
+    hints = typing.get_type_hints(cls)
+    out = {}
+    for k, v in fields.items():
+        hint = hints.get(k)
+        sub = next((t for t in (hint,) + typing.get_args(hint)
+                    if dataclasses.is_dataclass(t)), None)
+        out[k] = _build(sub, v) if sub and isinstance(v, dict) \
+            else _tuples(v)
+    return cls(**out)
+
+
+def _tuples(v):
+    return tuple(_tuples(x) for x in v) if isinstance(v, list) else v
 
 
 def calibrate(conf: dict, mix: dict, seed: int):
@@ -150,8 +171,9 @@ def calibrate(conf: dict, mix: dict, seed: int):
     return calib, float(lam)
 
 
-def build(cell: dict, seed: int, fault: Optional[Callable] = None):
-    """Weights, probe and scheduler; returns (sched, traffic, probe, info)."""
+def build(cell: dict, seed: int, family, fault: Optional[Callable] = None):
+    """Weights (made by ``family``), probe and scheduler; returns (sched,
+    traffic, probe, make_request, phases)."""
     import jax
     from repro import api as orca
     from repro.models import build as build_model
@@ -165,7 +187,8 @@ def build(cell: dict, seed: int, fault: Optional[Callable] = None):
     phases = {}
     t = time.perf_counter()
     model = build_model(model_config(conf))
-    params = W.make_program_params(cfg, seed, model.abstract_params())
+    params = W.make_program_params(family, cfg, seed,
+                                   model.abstract_params())
     jax.block_until_ready(params)
     phases["weights"] = time.perf_counter() - t
     t = time.perf_counter()
@@ -236,13 +259,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     cache_dir = use_compile_cache() if compile_cache else "off"
     sys.path.insert(0, str(CHECKOUT / "src"))
     from bench import check as C
+    from bench import families as F
     from bench import harness as H
     from bench import roofline as RF
+    family = F.load(cell["config"])
     counter = H.CompileCounter()
     log(f"device: {len(devices)} x {dev.device_kind} ({dev.platform}); "
         f"compile cache {cache_dir}")
     t_import = process_age()
-    sched, traffic, probe, make_request, phases = build(cell, seed, fault)
+    sched, traffic, probe, make_request, phases = build(cell, seed, family,
+                                                        fault)
     t = time.perf_counter()
     loop = H.Loop(sched, traffic, make_request)
     loop.start()
@@ -291,12 +317,14 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         chip = RF.peaks(dev.device_kind) if require_chip else \
             RF.ChipPeaks(1e12, 1e11, "test")
         ctx = types.SimpleNamespace(cfg=cell["config"]["program"],
-                                    mix=cell["mix"], window=window,
-                                    trace=red, chip=chip)
+                                    family=family, mix=cell["mix"],
+                                    window=window, trace=red, chip=chip,
+                                    program=sched, trace_dir=str(tdir))
         for m in cell["per_layer"]:
             v = reader(m["name"])(ctx)
             if v is not None:
                 metrics[m["name"]] = {"value": v, "unit": m["unit"]}
+        ctx.program = None
         device.update(busy_s=red.busy_s, window_s=red.window_s)
         breakdown = {"device_ops": [list(o) for o in red.device_ops],
                      "idle_gaps": [list(g) for g in red.idle_gaps]}
@@ -320,11 +348,11 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     picked = C.sample(served, mix["check"]["requests"], seed,
                       mix["serve"]["tokens_per_step"])
     t_pad = C.padded(traffic.max_context() + 1, R.Q_BLOCK)
-    canon = W.make_canonical(conf["program"], seed)
+    canon = W.make_canonical(family, conf["program"], seed)
     limits = cell["limits"]
     margin = limits.get("score_gap", limits.get("score_mean", 0.0))
-    readings = C.compare(conf["program"], canon, picked, probe, t_pad,
-                         score_limit=margin)
+    readings = C.compare(family, conf["program"], canon, picked, probe,
+                         t_pad, score_limit=margin)
     log(f"reference: {int(readings['requests'])} requests, "
         f"{int(readings['tokens'])} served tokens, "
         f"{int(readings['scores'])} probe scores, "
@@ -340,9 +368,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
                         for k, v in limits.items()}
     if control:
         for variant in C.VARIANTS[1:]:
-            result[variant] = C.compare(conf["program"], canon, picked,
-                                        probe, t_pad, variant=variant,
-                                        score_limit=margin)
+            result[variant] = C.compare(family, conf["program"], canon,
+                                        picked, probe, t_pad,
+                                        variant=variant, score_limit=margin)
         result["readings"] = readings
     return result
 

@@ -224,23 +224,9 @@ def reduce(events: List[TR.Event], module: str, scopes: Dict[str, Path],
 
 def run_state(ctx) -> Tuple[object, Optional[str]]:
     """(the scheduler, the trace directory) of the run whose readers get
-    ``ctx``.  ``bench.run.run_cell`` gives its readers neither: they are
-    its locals ``sched`` and ``tdir``, read from the frame of the
-    ``run_cell`` call that built this ``ctx``.  Fields of ``ctx`` of the
-    same meaning (``program``, ``trace_dir``) come first."""
-    have = {k: getattr(ctx, k) for k in ("program", "trace_dir")
-            if hasattr(ctx, k)}
-    if len(have) < 2:
-        f = sys._getframe(1)
-        while f is not None:
-            if f.f_code.co_name == "run_cell" \
-                    and f.f_locals.get("ctx") is ctx:
-                have.setdefault("program", f.f_locals.get("sched"))
-                have.setdefault("trace_dir", f.f_locals.get("tdir"))
-                break
-            f = f.f_back
-    tdir = have.get("trace_dir")
-    return have.get("program"), None if tdir is None else str(tdir)
+    ``ctx``: its fields ``program`` and ``trace_dir``, None where absent."""
+    tdir = getattr(ctx, "trace_dir", None)
+    return getattr(ctx, "program", None), None if tdir is None else str(tdir)
 
 
 def from_ctx(ctx) -> Optional[Scoped]:

@@ -1,11 +1,12 @@
-"""FLOP and byte counts against hand-worked values at both configurations'
-published widths, and the table of peaks."""
+"""The Llama family's FLOP and byte counts against hand-worked values at
+two configurations' published widths, and the table of peaks."""
 import json
 from pathlib import Path
 
 import pytest
 
 from bench import roofline as RF
+from bench.families import llama as FAM
 
 CONFIGS = Path(__file__).resolve().parents[1] / "configs"
 # granite-3.0-1b-a400m's published widths, as the program runs them
@@ -22,32 +23,32 @@ def _cfg(name):
 
 def test_active_weights_per_layer():
     # 960*15*64*2 + 960*5*64*2 + 3*960*2560
-    assert RF.layer_matmul_params(_cfg("smollm-360m")) == 9_830_400
+    assert FAM.layer_matmul_params(_cfg("smollm-360m")) == 9_830_400
     # 1024*16*64*2 + 1024*8*64*2 + 8 experts * 3*1024*512 + router 1024*32
-    assert RF.layer_matmul_params(_cfg("granite-moe-1b-a400m")) == 15_761_408
+    assert FAM.layer_matmul_params(_cfg("granite-moe-1b-a400m")) == 15_761_408
 
 
 def test_decode_token_flops():
     # 2*32*9830400 + 4*32*15*64*1000 + 2*960*49152
-    assert RF.token_flops(_cfg("smollm-360m"), 1000, head=True) == \
+    assert FAM.token_flops(_cfg("smollm-360m"), 1000, head=True) == \
         846_397_440
     # without the head, granite: 2*24*15761408 + 4*24*16*64*10
-    assert RF.token_flops(_cfg("granite-moe-1b-a400m"), 10, head=False) == \
+    assert FAM.token_flops(_cfg("granite-moe-1b-a400m"), 10, head=False) == \
         756_547_584 + 983_040
 
 
 def test_chunk_flops_are_causal():
     # 2 tokens from position 0 attend 1 and 2 positions
-    assert RF.chunk_flops(_cfg("smollm-360m"), 0, 2) == \
+    assert FAM.chunk_flops(_cfg("smollm-360m"), 0, 2) == \
         2 * 32 * 9_830_400 * 2 + 4 * 32 * 15 * 64 * 3
 
 
 def test_decode_attention_work():
-    f, b = RF.decode_attention_work(_cfg("smollm-360m"), [100])
+    f, b = FAM.decode_attention_work(_cfg("smollm-360m"), [100])
     assert f == 32 * 4 * 15 * 64 * 100
     # K and V of 100 positions in bf16, float32 query and partials out
     assert b == 32 * (2 * 5 * 64 * 100 * 2 + 4 * (2 * 15 * 64 + 2 * 15))
-    assert RF.decode_attention_work(_cfg("smollm-360m"), []) == (0.0, 0.0)
+    assert FAM.decode_attention_work(_cfg("smollm-360m"), []) == (0.0, 0.0)
 
 
 def test_roofline_share_and_bound():

@@ -186,31 +186,23 @@ def test_host_readers_read_the_windows_steps_only():
     assert RUN.reader("syncs.decode")(c) == 5
 
 
-def run_cell(sched, tdir, metrics):
-    """Stands for ``bench.run.run_cell``: its readers get a ``ctx`` that
-    names neither the scheduler nor the trace directory."""
-    ctx = types.SimpleNamespace(window=types.SimpleNamespace(
-        t_start=10.0, t_end=20.0, steps=[object()] * 2))
-    return S.run_state(ctx), [RUN.reader(m)(ctx) for m in metrics]
-
-
-def test_readers_find_the_program_in_run_cells_frame(tmp_path):
+def test_readers_read_the_program_and_trace_dir_from_ctx(tmp_path):
     recs = [Record(t0=11.0, t1=11.3, spans=[("orca.wait", 11.1, 11.25)],
                    counts={"reads": 5})]
     sched = types.SimpleNamespace(
         recorder=types.SimpleNamespace(records=recs),
         _engine=types.SimpleNamespace())
-    state, got = run_cell(sched, tmp_path, NEW)
-    assert state == (sched, str(tmp_path))
+    c = ctx(program=sched, trace_dir=tmp_path)
+    assert S.run_state(c) == (sched, str(tmp_path))
+    got = [RUN.reader(m)(c) for m in NEW]
     assert got[:2] == [pytest.approx(150.0), 5]
     assert got[2:] == [None] * 3       # no compiled step text
-    # outside a run_cell call, and in one without a trace
-    assert S.run_state(ctx()) == (None, None)
-    assert run_cell(sched, None, NEW[2:])[1] == [None] * 3
 
-
-def test_run_cell_keeps_the_locals_the_readers_read():
-    assert {"ctx", "sched", "tdir"} <= set(RUN.run_cell.__code__.co_varnames)
+    def run_cell(sched, tdir):
+        """Locals named as ``bench.run.run_cell``'s: not read."""
+        bare = ctx()
+        return S.run_state(bare), [RUN.reader(m)(bare) for m in NEW]
+    assert run_cell(sched, tmp_path) == ((None, None), [None] * len(NEW))
 
 
 def test_device_readers_read_nothing_from_a_cpu_trace(tmp_path):

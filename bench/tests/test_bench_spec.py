@@ -122,3 +122,30 @@ def test_every_configuration_is_used():
     assert used == {c["name"] for c in SPEC["configs"]}
     files = [c["file"] for c in SPEC["configs"]]
     assert len(files) == len(set(files))
+
+
+@pytest.mark.parametrize("name", [c["name"] for c in SPEC["configs"]])
+def test_every_configuration_names_a_family_with_the_whole_interface(name):
+    import inspect
+    from bench import families as F
+    conf = {c["name"]: c for c in SPEC["configs"]}[name]
+    body = json.loads((ROOT / conf["file"]).read_text())
+    family = F.load(body)
+    assert family.__name__ == body["family"]
+    params = {n: list(inspect.signature(getattr(family, n)).parameters)
+              for n in F.INTERFACE}
+    assert params == {
+        "canonical": ["cfg", "key"], "program_tree": ["cfg", "w"],
+        "hidden_states": ["cfg", "w", "tokens", "low"],
+        "logits": ["w", "h"],
+        "token_flops": ["cfg", "attended", "head"],
+        "chunk_flops": ["cfg", "start", "length"],
+        "decode_attention_work": ["cfg", "cached"]}
+
+
+def test_a_configuration_without_a_family_raises_naming_the_key():
+    from bench import families as F
+    with pytest.raises(ValueError, match="no 'family' key"):
+        F.load({"name": "x", "program": {}})
+    with pytest.raises(AttributeError, match="lacks"):
+        F.load({"name": "x", "family": "bench.roofline"})

@@ -8,6 +8,7 @@ import jax.numpy as jnp
 
 ROOT = Path(__file__).resolve().parents[2]
 SEED = 2 ** 40 + 3
+LLAMA = "bench.families.llama"
 
 PROGRAM = {"name": "tiny", "arch_type": "dense", "source": "test",
            "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 2,
@@ -33,24 +34,27 @@ LIMITS = {k: TINY_LIMITS[k] for k in json.loads(
     (ROOT / "bench" / "limits" / f"{CELL}.json").read_text())}
 
 
-def cell(moe: bool = False) -> dict:
+def cell(moe: bool = False, family: str = LLAMA, tied: bool = True) -> dict:
     spec = json.loads((ROOT / "BENCHMARK.json").read_text())
-    program = dict(PROGRAM)
+    program = dict(PROGRAM, tie_embeddings=tied)
     if moe:
         program.update(arch_type="moe", moe={"n_experts": 4, "top_k": 2})
     return {"workload": {"name": "tiny", "chips": 1},
-            "config": {"program": program}, "mix": MIX, "limits": LIMITS,
+            "config": {"name": "tiny", "family": family, "program": program},
+            "mix": MIX, "limits": LIMITS,
             "end_to_end": [m for m in spec["end_to_end"]
                            if CELL in m.get("workloads", [CELL])],
             "per_layer": [m for m in spec["per_layer"]
                           if CELL in m.get("workloads", [CELL])]}
 
 
-def run(fault=None, control=False, moe=False, seed=SEED):
+def run(fault=None, control=False, moe=False, seed=SEED, trace=False,
+        **config):
+    """One run of the tiny cell; ``config`` goes to ``cell``."""
     from bench import run as RUN
-    return RUN.run_cell("tiny", seed, 1.0, False, require_chip=False,
-                        cell=cell(moe), fault=fault, control=control,
-                        compile_cache=False)
+    return RUN.run_cell("tiny", seed, 1.0, trace, require_chip=False,
+                        cell=cell(moe, **config), fault=fault,
+                        control=control, compile_cache=False)
 
 
 def on_engine(patch):
